@@ -44,6 +44,7 @@ from benchmarks import (encoding_tradeoff, engine_throughput, exchange_stream,
                         fig5_latency, fig5_speedup, grad_compression,
                         interconnect_throughput, moe_dispatch, roofline_table,
                         scaling_projection)
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = [
     ("fig5_latency", fig5_latency.run),
@@ -156,6 +157,7 @@ def main(argv: list[str] | None = None) -> None:
         help="run only the named benchmark (repeatable); one of: "
              + ", ".join(name for name, _ in ALL))
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     selected = ALL
     if args.only:

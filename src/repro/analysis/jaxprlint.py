@@ -61,11 +61,11 @@ def iter_eqns(jaxpr) -> Iterator:
 
 
 def _sub_jaxprs(val) -> Iterator:
-    import jax
+    from jax.extend import core as jex
 
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jex.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jex.Jaxpr):
         yield val
     elif isinstance(val, (tuple, list)):
         for v in val:
@@ -212,7 +212,7 @@ def check_scan_consts(closed, path: str,
     ``num_consts`` operands; when such an operand is one of the program's
     *constvars* (baked-in data, not a traced argument), the array is
     embedded in the staged computation itself."""
-    import jax
+    from jax.extend import core as jex
 
     diags = []
     constvars = {id(v) for v in closed.jaxpr.constvars}
@@ -220,7 +220,7 @@ def check_scan_consts(closed, path: str,
         if eqn.primitive.name != "scan":
             continue
         body = eqn.params.get("jaxpr")
-        if not isinstance(body, jax.core.ClosedJaxpr):
+        if not isinstance(body, jex.ClosedJaxpr):
             continue
         n_consts = int(eqn.params.get("num_consts", 0))
         for v in eqn.invars[:n_consts]:
@@ -237,7 +237,7 @@ def check_scan_consts(closed, path: str,
         for sub in iter_eqns(body.jaxpr):
             if sub.primitive.name not in ("iota", "broadcast_in_dim"):
                 continue
-            if any(not isinstance(v, jax.core.Literal) for v in sub.invars):
+            if any(not isinstance(v, jex.Literal) for v in sub.invars):
                 continue
             out = sub.outvars[0].aval
             if int(math.prod(out.shape)) > limit:

@@ -1,6 +1,6 @@
 """Kernel write-set checker: the pack units and the Pallas grid tilings.
 
-The cumsum-scatter at the heart of every pack unit
+The rank-and-compact write-set at the heart of every pack unit
 (``spike_router._pack_indices`` / ``_pack_segmented_indices``) is the one
 place a rank bug silently corrupts a *neighbour's* frame — an off-by-one
 in the base offsets lands one segment's events inside the next
@@ -25,7 +25,9 @@ The second half statically checks the ``pallas_call`` tilings of the
 router kernels (``kernel.grid-bounds`` / ``kernel.grid-overlap`` /
 ``kernel.grid-coverage``): every output BlockSpec's write windows,
 enumerated over the whole grid through its index map, must stay in-bounds
-and pairwise disjoint (and cover the output, else a warning) — plus
+and pairwise disjoint (and cover the output, else a warning; a block that
+consecutive steps of a declared-sequential grid axis revisit is a resident
+accumulator, not an overlap) — plus
 ``kernel.aliasing``: donated input/output aliases must agree on
 shape/dtype.
 """
@@ -164,24 +166,44 @@ def check_pack_units(capacities, path: str = "spike_router"
 # ---------------------------------------------------------------------------
 
 
+def _block_size(dim) -> int:
+    """Element extent of one block dim: an int, a ``Blocked`` size, or 1 for
+    a squeezed dim (a vmapped grid axis)."""
+    if isinstance(dim, (int, np.integer)):
+        return int(dim)
+    return int(getattr(dim, "block_size", 1))
+
+
 def _block_windows(bm, grid, max_cells: int = 4096):
     """Yield (cell, start, shape) element windows of one block mapping."""
-    import jax
+    from jax.extend import core as jex
 
-    shape = tuple(int(s) if isinstance(s, (int, np.integer)) else 1
-                  for s in bm.block_shape)
+    shape = tuple(_block_size(s) for s in bm.block_shape)
     cells = list(itertools.islice(np.ndindex(*grid), max_cells + 1))
     truncated = len(cells) > max_cells
     if truncated:
         cells = cells[:max_cells]
-    cj = bm.index_map_jaxpr
+    index_map = jex.jaxpr_as_fun(bm.index_map_jaxpr)
     for cell in cells:
-        out = jax.core.eval_jaxpr(cj.jaxpr, cj.consts,
-                                  *(np.int32(i) for i in cell))
+        out = index_map(*(np.int32(i) for i in cell))
         start = tuple(int(b) * s for b, s in zip(out, shape))
         yield cell, start, shape
     if truncated:
         yield None, None, None                # sentinel: enumeration capped
+
+
+def _sequential_axes(eqn, gm) -> set[int]:
+    """Grid axes declared ``"arbitrary"`` (sequential — a reduction axis
+    whose output blocks may stay resident as accumulators).  Vmapped grid
+    axes are parallel; without declared semantics no axis qualifies."""
+    params = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem = getattr(params, "dimension_semantics", None)
+    if sem is None:
+        return set()
+    user_axes = [a for a in range(len(gm.grid))
+                 if a not in set(gm.vmapped_dims)]
+    return {a for a, s in zip(user_axes, sem)
+            if str(getattr(s, "value", s)).lower() == "arbitrary"}
 
 
 def check_pallas_calls(fn, args, path: str) -> list[Diagnostic]:
@@ -202,14 +224,15 @@ def check_pallas_calls(fn, args, path: str) -> list[Diagnostic]:
         gm = eqn.params["grid_mapping"]
         grid = tuple(int(g) for g in gm.grid)
         mappings = list(gm.block_mappings)
-        n_in = getattr(gm, "num_inputs", len(eqn.invars))
-        n_out = getattr(gm, "num_outputs", len(eqn.outvars))
+        n_in, n_out = gm.num_inputs, gm.num_outputs
+        sequential = _sequential_axes(eqn, gm)
         outs = mappings[n_in:n_in + n_out]
         for oi, bm in enumerate(outs):
             opath = f"{path}/pallas_call[{found - 1}]/out[{oi}]"
-            arr_shape = tuple(bm.array_shape_dtype.shape)
+            arr_shape = tuple(bm.array_aval.shape)
             seen: dict[tuple, tuple] = {}
             windows = []
+            prev_cell = prev = None
             for cell, start, shape in _block_windows(bm, grid):
                 if cell is None:
                     diags.append(Diagnostic(
@@ -217,6 +240,12 @@ def check_pallas_calls(fn, args, path: str) -> list[Diagnostic]:
                         "grid too large to enumerate — write-set "
                         "unverified", WARNING))
                     break
+                if start == prev and all(
+                        a in sequential for a, (i, j)
+                        in enumerate(zip(cell, prev_cell)) if i != j):
+                    prev_cell = cell  # revisit along sequential axes only:
+                    continue          # a resident accumulator block
+                prev_cell, prev = cell, start
                 if (any(s < 0 for s in start)
                         or any(s + b > a for s, b, a
                                in zip(start, shape, arr_shape))):
@@ -279,15 +308,15 @@ def _overlaps(a_start, a_shape, b_start, b_shape) -> bool:
 
 def check_router_kernels(capacity: int = 8, path: str = "spike_router"
                          ) -> list[Diagnostic]:
-    """Trace the three shipped router kernels on small shapes and verify
-    their grid tilings (shape-generic: the BlockSpec index maps don't
-    depend on the sizes)."""
+    """Trace the shipped router kernels and verify their grid tilings.  The
+    shapes span several row blocks and event tiles, so the sequential tile
+    axis and its resident accumulator blocks are exercised."""
     import jax.numpy as jnp
 
     from repro.core.routing import FWD_TABLE_SIZE, REV_TABLE_SIZE
     from repro.kernels.spike_router import spike_router as sr
 
-    n_src, n_dst, cap_in, n_steps = 3, 3, 4, 2
+    n_src, n_dst, cap_in, n_steps = 3, 9, 100, 2
     labels = jnp.zeros((n_src, cap_in), jnp.int32)
     valid = jnp.zeros((n_src, cap_in), jnp.int32)
     fwd = jnp.zeros((n_src, FWD_TABLE_SIZE), jnp.int32)
@@ -301,9 +330,9 @@ def check_router_kernels(capacity: int = 8, path: str = "spike_router"
     diags += check_pallas_calls(
         lambda *a: sr.exchange_stream_fwd(*a, capacity=capacity),
         (s_labels, s_valid, fwd, rev, en), f"{path}/exchange_stream_fwd")
-    m_labels = jnp.zeros((n_dst, 2 * cap_in), jnp.int32)
-    m_valid = jnp.zeros((n_dst, 2 * cap_in), jnp.int32)
+    m_labels = jnp.zeros((n_dst, 3 * cap_in), jnp.int32)
+    m_valid = jnp.zeros((n_dst, 3 * cap_in), jnp.int32)
     diags += check_pallas_calls(
-        lambda *a: sr.merge_pack_fwd(*a, capacity=capacity, n_segments=2),
+        lambda *a: sr.merge_pack_fwd(*a, capacity=capacity),
         (m_labels, m_valid, rev[0]), f"{path}/merge_pack_fwd")
     return diags

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro import compat
 from repro.analysis import hlo as hlolib
 from repro.configs.base import ModelConfig
 
@@ -88,7 +87,7 @@ def model_flops(cfg: ModelConfig, shape: dict, kind: str) -> float:
 def analyze(compiled, *, arch: str, shape_name: str, shape: dict, kind: str,
             mesh_desc: str, chips: int, cfg: ModelConfig,
             hlo_text: str | None = None) -> Roofline:
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

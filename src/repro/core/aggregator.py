@@ -35,7 +35,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map as _shard_map
 from repro.core import fabric as fablib
 from repro.core import routing
 from repro.core.events import EventFrame
@@ -399,8 +398,8 @@ class StarInterconnect:
         in_specs = (EventFrame(shard, shard, shard), *table_specs)
         out_specs = (EventFrame(shard, shard, shard),
                      ExchangeDrops(shard, shard, shard, shard))
-        return jax.jit(_shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                  out_specs=out_specs))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs))
 
     def stream_fn(self):
         """Multi-step exchange: scan T rounds inside one ``shard_map``.
@@ -429,5 +428,5 @@ class StarInterconnect:
         in_specs = (EventFrame(tshard, tshard, tshard), *table_specs)
         out_specs = (EventFrame(tshard, tshard, tshard),
                      ExchangeDrops(tshard, tshard, tshard, tshard))
-        return jax.jit(_shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                  out_specs=out_specs))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs))

@@ -141,11 +141,11 @@ def _arrival_times(out_times: jax.Array, out_valid: jax.Array,
     return jnp.where(out_valid, out_times + timing.recv_fixed_ns, 0)
 
 
-def _timed_mode(use_fused: bool) -> str:
-    """Kernel mode for the timed merges, resolved *eagerly* (never ``None``)
-    so the ops-level jit caches one entry per concrete mode — parity tests
-    monkeypatch ``repro.kernels.default_mode`` and must not hit a stale
-    ``mode=None`` trace."""
+def _kernel_mode(use_fused: bool) -> str:
+    """Kernel mode for the fused merges, resolved *eagerly* (never ``None``)
+    so the ops-level jit caches one entry per concrete mode — the chip
+    compile tests and parity tests monkeypatch ``repro.kernels.default_mode``
+    and must not hit a stale ``mode=None`` trace."""
     from repro.kernels import default_mode
 
     return default_mode() if use_fused else "jax"
@@ -163,7 +163,7 @@ def _fused_merge(labels, valid, rev, capacity: int, *, seg_lens, compact,
         labels, valid, rev, capacity=capacity, seg_lens=seg_lens,
         compact=compact, times=times,
         queue=None if timing is None else timing.queue,
-        mode=None if timing is None else _timed_mode(use_fused))
+        mode=_kernel_mode(use_fused))
     if timing is not None:
         out_l, out_v, out_t, dropped = outs
         out_t = _arrival_times(out_t, out_v, timing)
@@ -1004,7 +1004,8 @@ def fabric_route_step(state, frames: EventFrame, plan: FabricPlan, *,
 
         out_l, out_v, dropped = fused_exchange(
             frames.labels, frames.valid, state.fwd_tables, state.rev_tables,
-            levels[0].enables, capacity=plan.capacity)
+            levels[0].enables, capacity=plan.capacity,
+            mode=_kernel_mode(True))
         ingress = EventFrame(labels=out_l, times=jnp.zeros_like(out_l),
                              valid=out_v)
         zeros = jnp.zeros_like(dropped)
@@ -1459,8 +1460,6 @@ class FabricInterconnect:
         suppressed by jax), so the flag only changes peak memory where an
         accelerator backend is attached.
         """
-        from repro.compat import shard_map as _shard_map
-
         round_fn, shard, table_specs = self._round()
 
         def fn(frame, *tables):
@@ -1472,8 +1471,8 @@ class FabricInterconnect:
         in_specs = (EventFrame(shard, shard, shard), *table_specs)
         out_specs = (EventFrame(shard, shard, shard),
                      ExchangeDrops(shard, shard, shard, shard))
-        return jax.jit(_shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                  out_specs=out_specs),
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs),
                        donate_argnums=(0,) if donate else ())
 
     def stream_fn(self, *, donate: bool = False):
@@ -1484,8 +1483,6 @@ class FabricInterconnect:
         XLA's loop lowering regardless — this flag extends that to the
         caller-visible frame planes."""
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import shard_map as _shard_map
 
         round_fn, shard, table_specs = self._round()
 
@@ -1503,6 +1500,6 @@ class FabricInterconnect:
         in_specs = (EventFrame(tshard, tshard, tshard), *table_specs)
         out_specs = (EventFrame(tshard, tshard, tshard),
                      ExchangeDrops(tshard, tshard, tshard, tshard))
-        return jax.jit(_shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                  out_specs=out_specs),
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs),
                        donate_argnums=(0,) if donate else ())

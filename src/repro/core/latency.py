@@ -270,6 +270,7 @@ def _lindley_queue(arrivals: jax.Array, service_ns,
     ``cc_interval``/``cc_stall_ns`` model the transceiver's periodic
     clock-compensation pauses as extra service time on every Nth event.
     """
+    arrivals = jnp.asarray(arrivals, jnp.float32)   # ns; ints promote here
     n = arrivals.shape[0]
     service = jnp.full((n,), service_ns, jnp.float32)
     if cc_interval:

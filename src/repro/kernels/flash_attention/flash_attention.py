@@ -96,7 +96,7 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         true_kv_len: int | None = None,
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_kv: int = DEFAULT_BLOCK_KV,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool = False) -> jax.Array:
     """Core pallas_call.  Shapes (already padded to block multiples):
 
       q: [batch, q_heads, seq_q, d]      k, v: [batch, kv_heads, seq_kv, d]

@@ -39,7 +39,7 @@ def _lif_kernel(v_ref, i_ref, drive_ref, v_out_ref, i_out_ref, s_out_ref, *,
 def lif_step_fwd(v, i_syn, drive, *, alpha_mem: float, alpha_syn: float,
                  v_leak: float = 0.0, v_th: float = 1.0, v_reset: float = 0.0,
                  block_b: int = BLOCK_B, block_n: int = BLOCK_N,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Core pallas_call: all inputs f32[batch, n_neurons] (block multiples)."""
     batch, n = v.shape
     grid = (batch // block_b, n // block_n)

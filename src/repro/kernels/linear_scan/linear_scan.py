@@ -88,7 +88,7 @@ def _scan_kernel(q_ref, k_ref, v_ref, w_ref, u_ref, y_ref, h_scratch,
 
 def linear_scan_fwd(q, k, v, w, u, *, mode: str = "inclusive",
                     chunk: int = DEFAULT_CHUNK,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Core pallas_call.  Shapes (T already padded to a chunk multiple):
 
       q, k, w: [batch, heads, T, K]   v: [batch, heads, T, V]   u: [heads, K]

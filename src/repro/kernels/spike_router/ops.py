@@ -1,15 +1,14 @@
 """Public jit'd wrappers for the fused exchange datapath.
 
 ``route_and_pack``         egress only: fwd LUT + enable mask + capacity
-                           pack.
+                           pack (``interpret=True`` off the TPU).
 ``fused_exchange``         the full round (fwd LUT → route enables → merge →
                            pack → rev LUT), batched over destinations — what
                            ``repro.core.aggregator.route_step`` runs.
-``fused_exchange_stream``  T full rounds in one program: the multi-step
-                           kernel (grid over timesteps, LUTs resident in
-                           VMEM) on TPU, a ``lax.scan`` over the fused round
-                           elsewhere — what the streaming engine and
-                           ``benchmarks/exchange_stream.py`` run.
+``fused_exchange_stream``  T full rounds in one program: the round's kernel
+                           over a leading timestep grid axis on TPU, a
+                           ``lax.scan`` over the fused round elsewhere —
+                           what ``benchmarks/exchange_stream.py`` runs.
 ``fused_merge_pack``       merge + pack + rev LUT for streams whose fwd LUT
                            ran on the sender (the ``shard_map`` exchange
                            path); accepts a shared or per-stream rev LUT.
@@ -26,8 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import (MODE_INTERPRET, MODE_JAX, MODE_PALLAS,
-                           default_interpret, default_mode)
+from repro.kernels import MODE_INTERPRET, MODE_JAX, MODE_PALLAS, default_mode
 from repro.kernels.spike_router import ref as _ref
 from repro.kernels.spike_router.spike_router import (exchange_fwd,
                                                      exchange_stream_fwd,
@@ -37,7 +35,7 @@ from repro.kernels.spike_router.spike_router import (exchange_fwd,
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
 def route_and_pack(labels: jax.Array, valid: jax.Array, lut: jax.Array, *,
-                   capacity: int, interpret: bool | None = None):
+                   capacity: int, interpret: bool = False):
     """Fused LUT-route + enable-mask + capacity-pack.
 
     labels: int[..., n_events]; valid: bool/int[..., n_events];
@@ -46,8 +44,6 @@ def route_and_pack(labels: jax.Array, valid: jax.Array, lut: jax.Array, *,
     Returns (out_labels i32[..., capacity], out_valid bool[..., capacity],
              dropped i32[...]).
     """
-    if interpret is None:
-        interpret = default_interpret()
     lead = labels.shape[:-1]
     n = labels.shape[-1]
     labels2 = labels.reshape(-1, n).astype(jnp.int32)
@@ -189,18 +185,15 @@ def fused_merge_pack(labels: jax.Array, valid: jax.Array, rev_lut: jax.Array,
             labels, valid, rev_lut, capacity=capacity, seg_lens=seg_lens,
             compact=compact, times=times, queue=queue)
     elif mode in (MODE_PALLAS, MODE_INTERPRET):
+        # The kernel tiles the rank over fixed 128-event tiles; ``seg_lens``
+        # only steers the oracle (tiling is a scheduling choice, not a
+        # semantic one).
         lead = labels.shape[:-1]
         n = labels.shape[-1]
-        # The Pallas pack tiles over segments only when they are uniform;
-        # mixed-length sections fall back to the global unit (identical
-        # semantics — tiling is a scheduling choice, not a semantic one).
-        n_segments = 1
-        if seg_lens and len(set(seg_lens)) == 1:
-            n_segments = len(seg_lens)
         outs = merge_pack_fwd(
             labels.reshape(-1, n), valid.reshape(-1, n).astype(jnp.int32),
             rev_lut.astype(jnp.int32), capacity=capacity,
-            interpret=mode == MODE_INTERPRET, n_segments=n_segments,
+            interpret=mode == MODE_INTERPRET,
             times=None if times is None
             else times.reshape(-1, n).astype(jnp.int32),
             queue=queue)

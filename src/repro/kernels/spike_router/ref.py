@@ -132,7 +132,8 @@ def merge_pack_ref(labels, valid, rev_lut, *, capacity: int,
     if queue is None:
         return (out_labels.astype(jnp.int32), out_valid.astype(jnp.int32),
                 dropped.astype(jnp.int32))
-    arrive = frame.times.astype(jnp.int32) + _dest_queue_ns(capacity, queue)
+    arrive = (frame.times.astype(jnp.int32)
+              + _dest_queue_ns(capacity, queue)[0])
     out_times = jnp.where(out_valid, arrive, 0)
     return (out_labels.astype(jnp.int32), out_valid.astype(jnp.int32),
             out_times.astype(jnp.int32), dropped.astype(jnp.int32))

@@ -1,63 +1,49 @@
-"""Fused exchange datapath — the paper's §III routing as Pallas kernels.
+"""Fused exchange datapath — the paper's §III routing around a Pallas pack unit.
 
 Per exchange round the hardware does: fwd LUT (BRAM 16→16 lookup, one output
 bit is the routing enable) → enable masking → Aggregator star broadcast with
 static per-route enables → capacity-bounded pack (prefix-sum pack unit,
 congestion drop + count) → rev LUT (15→17) at the receiving Node-FPGA.
 
-Four kernels cover the datapath at increasing fusion depth:
+The pack unit is the kernel (``_pack_kernel``); the LUT lookups around it
+are XLA gathers, which the TPU runs natively (Mosaic lowers no gather from
+a 2^15/2^16-entry table).  Four wrappers drive the one kernel:
 
-``_router_kernel``        fwd LUT + mask + pack for one node's egress
-                          (the seed kernel, kept for ``route_and_pack``).
-``_exchange_kernel``      the whole round, batched over destinations: the
-                          grid iterates destinations; each cell reads the
-                          *shared* per-source label/valid buffers (never
-                          copied per destination), applies per-source fwd
-                          LUTs, gates with its enable column, merges all
-                          sources src-major, packs with the cumsum/scatter
-                          pack unit, and finishes with its own rev LUT.
-                          Used by ``route_step``.
-``_exchange_stream_kernel`` the multi-step variant: the grid is
-                          (destination, timestep) with the timestep as the
-                          fast axis, so each destination's rev LUT (and the
-                          shared fwd LUTs / enables) stays resident in VMEM
-                          while T frames stream through — one kernel launch
-                          routes a whole emulation run instead of T
-                          dispatches.  Used by ``fused_exchange_stream`` /
-                          the streaming engine.
-``_merge_pack_kernel``    merge + pack + rev LUT for one already-fwd-routed
-                          event stream; the rev LUT may be shared across the
-                          batch or per-row (hierarchical stacked routing);
+``spike_router_fwd``      fwd LUT + mask + pack for each node's egress.
+``exchange_fwd``          the whole round, batched over destinations: the
+                          fwd LUTs run once on the shared per-source stream
+                          (never copied per destination — the kernel maps
+                          one shared payload block into every grid cell),
+                          each destination packs with its own enable mask,
+                          then its own rev LUT.  Used by ``route_step`` and
+                          the plain-star fast path of the fabric executor.
+``exchange_stream_fwd``   T rounds in one program: ``exchange_fwd`` vmapped
+                          over the timestep (a leading grid axis).
+``merge_pack_fwd``        merge + pack + rev LUT for already-fwd-routed
+                          streams; the rev LUT may be shared across the
+                          batch or per-row (stacked hierarchical routing);
                           the stream may arrive as int16 wire words
-                          (``events.pack_wire16``), unpacked in-kernel, and
-                          the pack may be tiled over uniform source
-                          segments.  Used by the ``shard_map`` exchanges
-                          (``star_exchange`` / ``hierarchical_exchange``)
-                          where the fwd LUT runs on the sender before
-                          ``all_gather``.
+                          (``events.pack_wire16``), unpacked in-kernel; an
+                          int32 timestamp lane may ride the pack and pick up
+                          the destination's rank-dependent queueing.  Used
+                          by every merge of ``repro.core.fabric``.
 
-The pack unit comes in two forms: ``_pack`` (global cumsum + bounded
-scatter) and ``_pack_segmented`` (per-segment ranks + a small scan over
-segment totals + the same bounded scatter — identical semantics, the rank
-computation tiled over source blocks instead of one O(n_src·cap_in)
-chain).  The jnp twin with the compact-segments gather fast path is
-``repro.core.events.make_frame_segmented``.
+Kernel layout: each grid cell packs ``ROWS`` = 8 streams (one sublane tile),
+the batch padded to a multiple of 8; the event axis is padded to a multiple
+of ``TILE`` = 128 with invalid slots (which never change a rank).  In VMEM
+the cell's streams are transposed to columns (events on sublanes):
 
-TPU adaptation: the 64 Ki-entry LUT (256 KiB as int32) fits entirely in
-VMEM — the BRAM of the TPU — so tables are mapped as unblocked inputs.
-Event frames are small (≤ a few thousand events); each grid cell routes one
-frame:
+    rank   = strictly-lower-triangular 0/1 matmul per 128-event tile
+             (bf16 operands are exact for 0/1, f32 accumulation is exact
+             below 2^24) + a running base over the tiles' totals
+    keep   = ok & rank < capacity            (overflow → drop counter)
+    out[c] = Σ_i [rank_i == c] · payload_i   (one-hot compare + int32
+                                              select-and-reduce; each slot
+                                              has at most one writer)
 
-    entry  = LUT[label]                 (VMEM gather)
-    ok     = valid & enable-bit & route-enable
-    pos    = exclusive-prefix-sum(ok)   (compaction index)
-    out[pos] = wire-label where ok and pos < capacity
-
-The prefix-sum + masked scatter realizes the hardware's pack unit: arrival
-order is preserved, overflow events are dropped and counted, and invalid
-output slots are zero-filled.  Interpret mode executes the body directly on
-CPU (parity tests); on TPU the scatter lowers to a one-hot matmul-style
-scatter (small C).
+Arrival order is preserved, overflow events are dropped and counted, and
+invalid output slots are zero-filled — bit-exact with the jnp oracles in
+``ref.py`` (labels, valid, timestamps, drop counts).
 """
 
 from __future__ import annotations
@@ -67,6 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # Bit layout of the LUT entries is owned by repro.core.routing (the table
 # builders); the 16-bit wire-word layout by repro.core.events; the timed
@@ -76,91 +63,121 @@ from repro.core.events import WIRE_VALID_BIT
 from repro.core.latency import queue_wait_i32
 from repro.core.routing import (CHIP_LABEL_MASK as CHIP_MASK,
                                 FWD_ENABLE_BIT as ENABLE_BIT,
-                                FWD_TABLE_SIZE, REV_ENABLE_BIT,
-                                REV_TABLE_SIZE, WIRE_LABEL_MASK as WIRE_MASK)
+                                REV_ENABLE_BIT, WIRE_LABEL_MASK as WIRE_MASK)
+
+ROWS = 8      # streams per grid cell: one sublane tile
+TILE = 128    # events per grid step: one lane tile / MXU pass
 
 
-def _pack_indices(ok: jax.Array, capacity: int):
-    """Scatter index map of the global pack unit: exclusive-prefix-sum ranks
-    bounded by ``capacity``, rejected events parked in overflow slot
-    ``capacity`` (sliced away by the caller).  Returns ``(idx, keep)``.
+def _exclusive_rank(ok: jax.Array) -> jax.Array:
+    """Exclusive prefix sums of 0/1 ``ok`` along the last axis as one
+    strictly-upper-triangular matmul — exact: 0/1 in bf16, sums in f32
+    below 2^24."""
+    n = ok.shape[-1]
+    i = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    tri = (i < j).astype(jnp.bfloat16)
+    rank = jnp.dot(ok.astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32)
+    return rank.astype(jnp.int32)
 
-    This is the *write-set* of the cumsum-scatter — factored out so the
-    static kernel checker (``repro.analysis.kernelcheck``) can prove
-    in-bounds/disjointness on the exact index arithmetic the kernels run.
-    """
-    pos = jnp.cumsum(ok) - ok                    # exclusive prefix sum
+
+def _segment_indices(ok: jax.Array, base: jax.Array, capacity: int):
+    """Write-set of one pack-unit tile: ``ok`` [..., seg_len] 0/1 int32,
+    ``base`` [..., 1] the events offered before the tile.  Returns ``(idx,
+    keep)``: kept events go to their global arrival rank, rejected ones are
+    parked at index ``capacity``, which no output slot matches."""
+    pos = _exclusive_rank(ok) + base
     keep = (ok == 1) & (pos < capacity)
     return jnp.where(keep, pos, capacity), keep
 
 
 def _pack_segmented_indices(ok: jax.Array, capacity: int):
-    """Scatter index map of the segmented pack unit (``ok``: [n_seg,
-    seg_len]): per-segment exclusive ranks + an exclusive scan over segment
-    totals for the base offsets — ``base[seg] + within`` is exactly the
-    global arrival rank.  Returns ``(idx, keep)`` on the flattened stream,
-    overflow parked in slot ``capacity`` as in ``_pack_indices``."""
-    counts = jnp.sum(ok, axis=-1)                # [n_seg] per-segment totals
-    base = jnp.cumsum(counts) - counts           # exclusive scan, S elements
-    within = jnp.cumsum(ok, axis=-1) - ok        # per-segment exclusive ranks
-    pos = (base[:, None] + within).reshape(-1)
-    okf = ok.reshape(-1)
-    keep = (okf == 1) & (pos < capacity)
-    return jnp.where(keep, pos, capacity), keep
+    """Write-set of the pack unit over ``ok`` [n_seg, ..., seg_len]: the
+    tiles in sequence (the kernel's sequential grid axis), each ranked
+    within itself on top of a running base of the earlier tiles' totals —
+    ``base[seg] + within`` is exactly the global arrival rank.  Returns
+    ``(idx, keep)`` on the concatenated stream [..., n_seg·seg_len].
+
+    Factored out so the static kernel checker
+    (``repro.analysis.kernelcheck``) proves in-bounds/disjointness on the
+    index arithmetic the kernel runs.
+    """
+    base = jnp.zeros((*ok.shape[1:-1], 1), jnp.int32)
+    idx, keep = [], []
+    for seg in ok:
+        i, k = _segment_indices(seg, base, capacity)
+        idx.append(i)
+        keep.append(k)
+        base = base + jnp.sum(seg, axis=-1, keepdims=True)
+    return jnp.concatenate(idx, axis=-1), jnp.concatenate(keep, axis=-1)
 
 
-def _pack(ok: jax.Array, payload: jax.Array, capacity: int,
-          payload2: jax.Array | None = None):
-    """The global pack unit: cumsum-compact ``payload`` where ``ok``, bounded
-    by ``capacity``.  Returns (packed_payload [capacity], packed_valid
-    [capacity], dropped scalar); with ``payload2`` (the timed datapath's
-    timestamp lane) a fourth array rides the same scatter:
-    (packed_payload, packed_payload2, packed_valid, dropped)."""
-    # Park rejected events in an overflow slot, then slice it away.
-    idx, keep = _pack_indices(ok, capacity)
-    out_p = jnp.zeros((capacity + 1,), jnp.int32).at[idx].set(
-        jnp.where(keep, payload, 0))
-    out_v = jnp.zeros((capacity + 1,), jnp.int32).at[idx].max(
-        jnp.where(keep, 1, 0))
-    dropped = jnp.sum(ok) - jnp.sum(jnp.where(keep, 1, 0))
-    if payload2 is None:
-        return out_p[:capacity], out_v[:capacity], dropped
-    out_p2 = jnp.zeros((capacity + 1,), jnp.int32).at[idx].set(
-        jnp.where(keep, payload2, 0))
-    return out_p[:capacity], out_p2[:capacity], out_v[:capacity], dropped
+def _pack_indices(ok: jax.Array, capacity: int):
+    """Write-set of the global (one-tile) pack unit: ``ok`` [..., n]."""
+    return _pack_segmented_indices(ok[None], capacity)
+
+
+def _pack_tile(ok: jax.Array, lanes, base: jax.Array, capacity: int):
+    """One grid step of the pack unit on ``r`` streams.
+
+    ok: [r, seg_len] 0/1; lanes: payloads [r, seg_len] (wire labels[,
+    timestamps]); base: [r, 1] events offered before this tile.  Returns
+    (the tile's contribution to every lane's packed output and then to the
+    valid mask, each [r, capacity] — zero outside its kept events' slots —,
+    the next base).  Compaction is a one-hot compare of the rank against the
+    output slot plus an int32 select-and-reduce over the events (sublanes,
+    after a transpose): kept events write distinct slots, so each sum is
+    the one writer's value, and tiles add up exactly.
+    """
+    idx, keep = _segment_indices(ok, base, capacity)
+    at = idx.T                                          # [seg_len, r]
+    cols = [lane.T for lane in lanes] + [keep.astype(jnp.int32).T]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, capacity), 1)
+    rows = [[] for _ in cols]
+    for r in range(ok.shape[0]):
+        hit = at[:, r:r + 1] == slot                    # [seg_len, capacity]
+        for out, col in zip(rows, cols):
+            out.append(jnp.sum(jnp.where(hit, col[:, r:r + 1], 0), axis=0,
+                               keepdims=True))
+    return ([jnp.concatenate(out, axis=0) for out in rows],
+            base + jnp.sum(ok, axis=-1, keepdims=True))
 
 
 def _pack_segmented(ok: jax.Array, payload: jax.Array, capacity: int,
                     payload2: jax.Array | None = None):
-    """The segmented (two-level) pack unit, tiled over source segments.
+    """The pack unit on one stream split into tiles — the kernel's grid
+    steps in sequence.
 
-    ok, payload: [n_seg, seg_len] — contiguous equal-length segments of the
-    merge stream (one per source block).  Level 1 ranks events *within* each
-    segment (short independent prefix sums instead of one O(n_seg·seg_len)
-    chain); level 2 is a tiny exclusive scan over the per-segment totals for
-    the base offsets; the bounded scatter then places ``base[seg] + rank``,
-    which is exactly the global arrival rank — bit-exact with ``_pack`` on
-    the flattened stream, including drop counts and arrival order.
-    Returns (packed_payload [capacity], packed_valid [capacity], dropped);
-    with ``payload2`` the timestamp lane rides the same scatter, as in
-    ``_pack``.
+    ok, payload (and ``payload2``, the timed datapath's timestamp lane):
+    [n_seg, seg_len] int32.  Returns (packed_payload [capacity],
+    [packed_payload2 [capacity],] packed_valid [capacity], dropped scalar) —
+    arrival order preserved, overflow dropped and counted, empty slots 0.
     """
-    okf = ok.reshape(-1)
-    idx, keep = _pack_segmented_indices(ok, capacity)
-    out_p = jnp.zeros((capacity + 1,), jnp.int32).at[idx].set(
-        jnp.where(keep, payload.reshape(-1), 0))
-    out_v = jnp.zeros((capacity + 1,), jnp.int32).at[idx].max(
-        jnp.where(keep, 1, 0))
-    dropped = jnp.sum(okf) - jnp.sum(jnp.where(keep, 1, 0))
-    if payload2 is None:
-        return out_p[:capacity], out_v[:capacity], dropped
-    out_p2 = jnp.zeros((capacity + 1,), jnp.int32).at[idx].set(
-        jnp.where(keep, payload2.reshape(-1), 0))
-    return out_p[:capacity], out_p2[:capacity], out_v[:capacity], dropped
+    lanes = [payload] + ([] if payload2 is None else [payload2])
+    acc = [jnp.zeros((1, capacity), jnp.int32) for _ in range(len(lanes) + 1)]
+    base = jnp.zeros((1, 1), jnp.int32)
+    for seg in range(ok.shape[0]):
+        contrib, base = _pack_tile(ok[seg][None],
+                                   [lane[seg][None] for lane in lanes], base,
+                                   capacity)
+        acc = [a + c for a, c in zip(acc, contrib)]
+    dropped = base[0, 0] - jnp.sum(acc[-1])
+    return (*(a[0] for a in acc), dropped)
+
+
+def _pack(ok: jax.Array, payload: jax.Array, capacity: int,
+          payload2: jax.Array | None = None):
+    """The global (one-tile) pack unit on one stream: ``ok``/``payload``
+    [n] (see ``_pack_segmented``)."""
+    return _pack_segmented(
+        ok[None], payload[None], capacity,
+        payload2=None if payload2 is None else payload2[None])
 
 
 def _dest_queue_ns(capacity: int, queue: tuple[int, int, int]) -> jax.Array:
-    """Destination-side queueing delay by pack rank (== output slot index).
+    """Destination-side queueing delay by pack rank (== output slot index),
+    as a [1, capacity] row.
 
     ``queue`` is the static (service_ns, cc_interval, stall_total_ns) triple
     from ``latency.TimedWire.queue``: the event at output slot ``r`` waited
@@ -168,280 +185,202 @@ def _dest_queue_ns(capacity: int, queue: tuple[int, int, int]) -> jax.Array:
     ``latency.queue_wait_i32`` (the integer twin of
     ``latency.hop_delays(...).total_ns``) evaluated on the slot index.
     """
-    # TPU requires ≥2D iota; squeeze back to the slot vector.
-    rank = jax.lax.broadcasted_iota(jnp.int32, (capacity, 1), 0)[:, 0]
+    rank = jax.lax.broadcasted_iota(jnp.int32, (1, capacity), 1)
     return queue_wait_i32(rank, queue)
 
 
-def _router_kernel(labels_ref, valid_ref, lut_ref, out_labels_ref,
-                   out_valid_ref, dropped_ref, *, capacity: int):
-    labels = labels_ref[0]                       # [N] int32
-    valid = valid_ref[0]                         # [N] int32 (0/1)
-    lut = lut_ref[...]                           # [65536] int32, fully in VMEM
+def _pack_kernel(ok_ref, payload_ref, *refs, capacity: int, wire16: bool,
+                 queue: tuple[int, int, int] | None):
+    """One grid step: ``ROWS`` streams × one ``TILE`` of their events.
 
-    entry = jnp.take(lut, labels & CHIP_MASK, axis=0)
-    wire = entry & WIRE_MASK
-    enabled = (entry >> ENABLE_BIT) & 1
-    ok = (valid * enabled).astype(jnp.int32)     # [N]
+    Grid (row block, tile): the tile axis is sequential, and the output
+    blocks stay resident across it as accumulators; the drop block doubles
+    as the running count of offered events (the rank base) until the last
+    tile turns it into the drop count.
 
-    out_l, out_v, dropped = _pack(ok, wire, capacity)
-    out_labels_ref[0] = out_l
-    out_valid_ref[0] = out_v
-    dropped_ref[0, 0] = dropped
-
-
-def _exchange_body(labels, valid, fwd, rev, en_col, capacity: int):
-    """Full fwd→enable→merge→pack→rev round for one destination.
-
-    labels, valid: [n_src, cap_in]; fwd: [n_src, 2^16]; rev: [2^15];
-    en_col: [n_src].  Returns (out_labels [capacity], out_valid [capacity],
-    dropped scalar).
-    """
-    # fwd LUT: per-source table gather from the flattened stacked tables.
-    src = jax.lax.broadcasted_iota(jnp.int32, labels.shape, 0)
-    flat_idx = (src * FWD_TABLE_SIZE + (labels & CHIP_MASK)).reshape(-1)
-    entry = jnp.take(fwd.reshape(-1), flat_idx, axis=0).reshape(labels.shape)
-    wire = entry & WIRE_MASK
-    fwd_en = (entry >> ENABLE_BIT) & 1
-
-    # Aggregator: static route enable for (src, this destination).
-    ok = (valid * fwd_en * en_col[:, None]).astype(jnp.int32)
-
-    # Multi-source merge is src-major (arrival order); the segmented pack
-    # tiles the rank computation over the source blocks.
-    packed_w, packed_v, dropped = _pack_segmented(ok, wire, capacity)
-
-    # rev LUT at the receiving node; rev-disabled events keep their slot but
-    # are invalidated silently (not counted as congestion drops) — §III.
-    rentry = jnp.take(rev, packed_w & WIRE_MASK, axis=0)
-    chip = rentry & CHIP_MASK
-    rev_en = (rentry >> REV_ENABLE_BIT) & 1
-    out_v = packed_v * rev_en
-    return jnp.where(out_v == 1, chip, 0), out_v, dropped
-
-
-def _exchange_kernel(labels_ref, valid_ref, fwd_ref, rev_ref, enables_ref,
-                     out_labels_ref, out_valid_ref, dropped_ref, *,
-                     capacity: int):
-    """One destination per grid cell: full fwd→enable→merge→pack→rev round."""
-    out_l, out_v, dropped = _exchange_body(
-        labels_ref[...],                         # [n_src, cap_in] shared
-        valid_ref[...],                          # [n_src, cap_in] int32
-        fwd_ref[...],                            # [n_src, 2^16] per-source
-        rev_ref[0],                              # [2^15] this destination's
-        enables_ref[...][:, 0],                  # [n_src] int32
-        capacity)
-    out_labels_ref[0] = out_l
-    out_valid_ref[0] = out_v
-    dropped_ref[0, 0] = dropped
-
-
-def _exchange_stream_kernel(labels_ref, valid_ref, fwd_ref, rev_ref,
-                            enables_ref, out_labels_ref, out_valid_ref,
-                            dropped_ref, *, capacity: int):
-    """One (destination, timestep) per grid cell.
-
-    The timestep is the fast grid axis, so the destination-side blocks (rev
-    LUT, enable column) and the shared fwd LUTs keep their VMEM residency
-    across a destination's whole stream; only the per-step frame block moves.
-    """
-    out_l, out_v, dropped = _exchange_body(
-        labels_ref[0],                           # [n_src, cap_in] step frame
-        valid_ref[0],
-        fwd_ref[...],
-        rev_ref[0],
-        enables_ref[...][:, 0],
-        capacity)
-    out_labels_ref[0, 0] = out_l
-    out_valid_ref[0, 0] = out_v
-    dropped_ref[0, 0] = dropped
-
-
-def _merge_pack_kernel(labels_ref, valid_ref, *refs, capacity: int,
-                       batched_rev: bool = False, n_segments: int = 1,
-                       wire16: bool = False,
-                       queue: tuple[int, int, int] | None = None):
-    """Merge + pack + rev LUT for one pre-routed wire-label stream.
-
-    ``wire16``: the label stream carries int16 wire words (15-bit label,
-    valid flag in bit 15, as emitted by ``events.pack_wire16``) — the word is
-    unpacked here, inside the kernel, and its embedded valid bit is ANDed
-    with the caller's (route-enable) mask.  ``n_segments > 1`` tiles the pack
-    unit over that many equal source segments.
-
-    Timed datapath (``queue`` set): an int32 timestamp lane travels alongside
-    the wire words (``times_ref``), rides the pack unit's scatter, and picks
-    up the load-dependent queueing delay of its arrival rank
-    (``_dest_queue_ns``) in-kernel — the functional datapath and the latency
-    model as one program.  Ref order then is
-    (labels, valid, times, rev | out_labels, out_valid, out_times, dropped).
+    ok: [ROWS, TILE] 0/1; payload: [ROWS, TILE], or [1, TILE] shared by
+    every row (the exchange's per-source stream, one per destination's
+    enable mask).  ``wire16``: the payload carries int16 wire words (15-bit
+    label, valid flag in bit 15, as emitted by ``events.pack_wire16``) —
+    the word is unpacked here and its valid bit ANDed with ``ok``.  Timed
+    datapath (``queue`` set): an int32 timestamp lane (``times_ref``) rides
+    the pack and picks up the destination queueing of its arrival rank
+    (``_dest_queue_ns``).  Ref order: (ok, payload[, times] | out_payload,
+    [out_times,] out_valid, dropped).
     """
     if queue is not None:
-        times_ref, rev_ref, out_labels_ref, out_valid_ref, out_times_ref, \
-            dropped_ref = refs
+        times_ref, *out_refs, drop_ref = refs
     else:
-        times_ref = out_times_ref = None
-        rev_ref, out_labels_ref, out_valid_ref, dropped_ref = refs
-    labels = labels_ref[0]                       # [N] wire labels / words
-    ok = valid_ref[0].astype(jnp.int32)          # [N] 0/1
-    rev = rev_ref[0] if batched_rev else rev_ref[...]   # [2^15]
+        times_ref = None
+        *out_refs, drop_ref = refs
+    tile = pl.program_id(1)
 
+    @pl.when(tile == 0)
+    def _init():
+        for ref in (*out_refs, drop_ref):
+            ref[...] = jnp.zeros(ref.shape, jnp.int32)
+
+    ok = ok_ref[...]
+    payload = jnp.broadcast_to(payload_ref[...], ok.shape)
     if wire16:
-        word = labels.astype(jnp.int32) & 0xFFFF
+        word = payload & 0xFFFF
         ok = ok * ((word >> WIRE_VALID_BIT) & 1)
-        labels = word & WIRE_MASK
-    else:
-        labels = labels.astype(jnp.int32)
+        payload = word & WIRE_MASK
+    lanes = [payload] + ([] if times_ref is None else [times_ref[...]])
+    contrib, drop_ref[...] = _pack_tile(ok, lanes, drop_ref[...], capacity)
+    for ref, c in zip(out_refs, contrib):
+        ref[...] += c
 
-    times = None if times_ref is None else times_ref[0]
-    if n_segments > 1:
-        seg_len = ok.shape[0] // n_segments
-        packed = _pack_segmented(
-            ok.reshape(n_segments, seg_len),
-            labels.reshape(n_segments, seg_len), capacity,
-            payload2=times if times is None
-            else times.reshape(n_segments, seg_len))
-    else:
-        packed = _pack(ok, labels, capacity, payload2=times)
-    if queue is not None:
-        packed_w, packed_t, packed_v, dropped = packed
-    else:
-        packed_w, packed_v, dropped = packed
+    @pl.when(tile == pl.num_programs(1) - 1)
+    def _finish():
+        out_v_ref = out_refs[-1]
+        valid = out_v_ref[...]
+        drop_ref[...] -= jnp.sum(valid, axis=1, keepdims=True)
+        if queue is not None:
+            # Arrival time = departure + accumulated fixed path (already in
+            # the lane) + this destination's rank-dependent queueing.
+            out_t_ref = out_refs[1]
+            out_t_ref[...] = jnp.where(
+                valid == 1, out_t_ref[...] + _dest_queue_ns(capacity, queue),
+                0)
 
-    rentry = jnp.take(rev, packed_w & WIRE_MASK, axis=0)
-    chip = rentry & CHIP_MASK
-    rev_en = (rentry >> REV_ENABLE_BIT) & 1
-    out_v = packed_v * rev_en
-    out_labels_ref[0] = jnp.where(out_v == 1, chip, 0)
-    out_valid_ref[0] = out_v
-    if queue is not None:
-        # Arrival time = departure + accumulated fixed path (already in the
-        # lane) + this destination's rank-dependent queueing; invalid slots
-        # keep the frame invariant of zeroed payloads.
-        arrive = packed_t + _dest_queue_ns(capacity, queue)
-        out_times_ref[0] = jnp.where(out_v == 1, arrive, 0)
-    dropped_ref[0, 0] = dropped
+
+def _pack_call(ok: jax.Array, payload: jax.Array, *, capacity: int,
+               interpret: bool, times: jax.Array | None = None,
+               queue: tuple[int, int, int] | None = None,
+               wire16: bool = False):
+    """Run the pack kernel over a batch of streams.
+
+    ok: int32[b, n] 0/1; payload: int32[b, n], or int32[1, n] shared by all
+    b streams; ``times`` (int32[b, n]) with ``queue`` adds the timed lane.
+    The batch is padded to a multiple of ``ROWS`` and the events to a
+    multiple of ``TILE`` with invalid slots.
+    Returns (packed_payload i32[b, capacity], [packed_times i32[b,
+    capacity],] packed_valid i32[b, capacity], dropped i32[b]).
+    """
+    b, n = ok.shape
+    rows = -(-b // ROWS) * ROWS
+    width = max(1, -(-n // TILE)) * TILE      # ≥ 1 tile: outputs initialized
+    shared = payload.shape[0] != b
+
+    def pad(x, r):
+        return jnp.pad(x.astype(jnp.int32),
+                       ((0, r - x.shape[0]), (0, width - n)))
+
+    row_spec = pl.BlockSpec((ROWS, TILE), lambda i, t: (i, t))
+    pay_spec = (pl.BlockSpec((1, TILE), lambda i, t: (0, t)) if shared
+                else row_spec)
+    out_spec = pl.BlockSpec((ROWS, capacity), lambda i, t: (i, 0))
+    drop_spec = pl.BlockSpec((ROWS, 1), lambda i, t: (i, 0))
+    out_lane = jax.ShapeDtypeStruct((rows, capacity), jnp.int32)
+    n_lanes = 2 if times is None else 3
+
+    operands = [pad(ok, rows), pad(payload, 1 if shared else rows)]
+    in_specs = [row_spec, pay_spec]
+    if times is not None:
+        operands.append(pad(times, rows))
+        in_specs.append(row_spec)
+    kernel = functools.partial(_pack_kernel, capacity=capacity,
+                               wire16=wire16, queue=queue)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(rows // ROWS, width // TILE),
+        in_specs=in_specs,
+        out_specs=(out_spec,) * n_lanes + (drop_spec,),
+        out_shape=(out_lane,) * n_lanes
+        + (jax.ShapeDtypeStruct((rows, 1), jnp.int32),),
+        # The tile axis carries the accumulators: sequential ("arbitrary").
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*operands)
+    return (*(o[:b] for o in outs[:-1]), outs[-1][:b, 0])
+
+
+def _rev_lookup(rev: jax.Array, packed: jax.Array, packed_valid: jax.Array):
+    """rev LUT at the receiving node (XLA gather): shared [2^15] or one
+    table per stream [b, 2^15].  Rev-disabled events keep their slot but are
+    invalidated silently (not counted as congestion drops) — §III."""
+    wire = packed & WIRE_MASK
+    if rev.ndim == 1:
+        entry = jnp.take(rev, wire, axis=0)
+    else:
+        entry = jnp.take_along_axis(rev, wire, axis=1)
+    out_v = packed_valid * ((entry >> REV_ENABLE_BIT) & 1)
+    return jnp.where(out_v == 1, entry & CHIP_MASK, 0), out_v
 
 
 def spike_router_fwd(labels: jax.Array, valid: jax.Array, lut: jax.Array, *,
-                     capacity: int, interpret: bool = True):
-    """Egress-only pallas_call (fwd LUT + mask + pack).
+                     capacity: int, interpret: bool = False):
+    """Egress only: fwd LUT + enable mask + pack.
 
     labels, valid: int32[batch, n_events]; lut: int32[65536].
     Returns (out_labels i32[batch, capacity], out_valid i32[batch, capacity],
              dropped i32[batch, 1]).
     """
-    batch, n_events = labels.shape
-    grid = (batch,)
-
-    ev_spec = pl.BlockSpec((1, n_events), lambda b: (b, 0))
-    lut_spec = pl.BlockSpec(lut.shape, lambda b: (0,))
-    out_spec = pl.BlockSpec((1, capacity), lambda b: (b, 0))
-    drop_spec = pl.BlockSpec((1, 1), lambda b: (b, 0))
-
-    kernel = functools.partial(_router_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[ev_spec, ev_spec, lut_spec],
-        out_specs=(out_spec, out_spec, drop_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(labels, valid, lut)
+    entry = jnp.take(lut, labels & CHIP_MASK, axis=0)
+    ok = valid * ((entry >> ENABLE_BIT) & 1)
+    out_l, out_v, dropped = _pack_call(ok, entry & WIRE_MASK,
+                                       capacity=capacity, interpret=interpret)
+    return out_l, out_v, dropped[:, None]
 
 
 def exchange_fwd(labels: jax.Array, valid: jax.Array, fwd_luts: jax.Array,
                  rev_luts: jax.Array, enables: jax.Array, *,
-                 capacity: int, interpret: bool = True):
-    """Full-round pallas_call, one grid cell per destination.
+                 capacity: int, interpret: bool = False):
+    """Full round: fwd LUT → enable → merge → pack → rev LUT, batched over
+    destinations.
 
     labels, valid: int32[n_src, cap_in] (shared across destinations);
     fwd_luts: int32[n_src, 2^16]; rev_luts: int32[n_dst, 2^15];
-    enables: int32[n_src, n_dst].
+    enables: int32[n_src, n_dst].  The merge is src-major (arrival order).
     Returns (out_labels i32[n_dst, capacity], out_valid i32[n_dst, capacity],
              dropped i32[n_dst, 1]).
     """
     n_src, cap_in = labels.shape
-    n_dst = rev_luts.shape[0]
-    grid = (n_dst,)
-
-    shared = lambda shape: pl.BlockSpec(shape, lambda d: (0,) * len(shape))
-    rev_spec = pl.BlockSpec((1, rev_luts.shape[1]), lambda d: (d, 0))
-    en_spec = pl.BlockSpec((n_src, 1), lambda d: (0, d))
-    out_spec = pl.BlockSpec((1, capacity), lambda d: (d, 0))
-    drop_spec = pl.BlockSpec((1, 1), lambda d: (d, 0))
-
-    kernel = functools.partial(_exchange_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[shared((n_src, cap_in)), shared((n_src, cap_in)),
-                  shared(fwd_luts.shape), rev_spec, en_spec],
-        out_specs=(out_spec, out_spec, drop_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((n_dst, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((n_dst, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((n_dst, 1), jnp.int32),
-        ),
-        interpret=interpret,
-    )(labels, valid, fwd_luts, rev_luts, enables)
+    entry = jnp.take_along_axis(fwd_luts, labels & CHIP_MASK, axis=1)
+    sent = valid * ((entry >> ENABLE_BIT) & 1)           # [n_src, cap_in]
+    # Per-destination enable mask over the shared src-major stream.
+    ok = (enables.T[:, :, None] * sent[None]).reshape(-1, n_src * cap_in)
+    wire = (entry & WIRE_MASK).reshape(1, n_src * cap_in)
+    packed, packed_v, dropped = _pack_call(ok, wire, capacity=capacity,
+                                           interpret=interpret)
+    out_l, out_v = _rev_lookup(rev_luts, packed, packed_v)
+    return out_l, out_v, dropped[:, None]
 
 
 def exchange_stream_fwd(labels: jax.Array, valid: jax.Array,
                         fwd_luts: jax.Array, rev_luts: jax.Array,
                         enables: jax.Array, *, capacity: int,
-                        interpret: bool = True):
-    """Multi-step full-round pallas_call: one grid cell per (dst, timestep).
+                        interpret: bool = False):
+    """T full rounds in one program: ``exchange_fwd`` over a leading
+    timestep grid axis (routing tables are configuration, shared by every
+    step).
 
     labels, valid: int32[T, n_src, cap_in] per-timestep egress frames;
     fwd_luts: int32[n_src, 2^16]; rev_luts: int32[n_dst, 2^15];
-    enables: int32[n_src, n_dst].  The destination is the *slow* grid axis,
-    so every LUT block stays resident while the T frames stream through.
+    enables: int32[n_src, n_dst].
     Returns (out_labels i32[T, n_dst, capacity],
              out_valid i32[T, n_dst, capacity], dropped i32[T, n_dst]).
     """
-    n_steps, n_src, cap_in = labels.shape
-    n_dst = rev_luts.shape[0]
-    grid = (n_dst, n_steps)
-
-    ev_spec = pl.BlockSpec((1, n_src, cap_in), lambda d, t: (t, 0, 0))
-    fwd_spec = pl.BlockSpec(fwd_luts.shape, lambda d, t: (0, 0))
-    rev_spec = pl.BlockSpec((1, rev_luts.shape[1]), lambda d, t: (d, 0))
-    en_spec = pl.BlockSpec((n_src, 1), lambda d, t: (0, d))
-    out_spec = pl.BlockSpec((1, 1, capacity), lambda d, t: (t, d, 0))
-    drop_spec = pl.BlockSpec((1, 1), lambda d, t: (t, d))
-
-    kernel = functools.partial(_exchange_stream_kernel, capacity=capacity)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[ev_spec, ev_spec, fwd_spec, rev_spec, en_spec],
-        out_specs=(out_spec, out_spec, drop_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((n_steps, n_dst, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((n_steps, n_dst, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((n_steps, n_dst), jnp.int32),
-        ),
-        interpret=interpret,
-    )(labels, valid, fwd_luts, rev_luts, enables)
+    step = functools.partial(exchange_fwd, capacity=capacity,
+                             interpret=interpret)
+    out_l, out_v, dropped = jax.vmap(step, in_axes=(0, 0, None, None, None))(
+        labels, valid, fwd_luts, rev_luts, enables)
+    return out_l, out_v, dropped[..., 0]
 
 
 def merge_pack_fwd(labels: jax.Array, valid: jax.Array, rev_lut: jax.Array, *,
-                   capacity: int, interpret: bool = True,
-                   n_segments: int = 1, times: jax.Array | None = None,
+                   capacity: int, interpret: bool = False,
+                   times: jax.Array | None = None,
                    queue: tuple[int, int, int] | None = None):
-    """Merge-pack-rev pallas_call over a batch of pre-routed streams.
+    """Merge + pack + rev LUT over a batch of pre-routed streams.
 
     labels, valid: [batch, n_events] wire labels (fwd LUT already applied,
     route enables already folded into ``valid``).  ``labels`` is int32 wire
     labels, or int16 wire words (``events.pack_wire16``: 15-bit label plus
     the valid flag in bit 15) unpacked inside the kernel and ANDed with
-    ``valid``.  ``n_segments`` tiles the pack unit over that many
-    equal-length source segments (must divide ``n_events``).
+    ``valid``.
     rev_lut: int32[2^15] shared across the batch, or int32[batch, 2^15] with
     one reverse LUT per stream (stacked hierarchical routing).
     Returns (out_labels i32[batch, capacity], out_valid i32[batch, capacity],
@@ -453,53 +392,14 @@ def merge_pack_fwd(labels: jax.Array, valid: jax.Array, rev_lut: jax.Array, *,
     destination's rank-dependent queueing in-kernel; the return gains
     ``out_times i32[batch, capacity]`` before ``dropped``.
     """
-    batch, n_events = labels.shape
-    grid = (batch,)
-    wire16 = labels.dtype == jnp.int16
-    if n_events % n_segments:
-        raise ValueError(f"n_segments {n_segments} must divide the stream "
-                         f"length {n_events}")
     if (times is None) != (queue is None):
         raise ValueError("the timed merge needs both the timestamp lane and "
                          "the static queue constants (times XOR queue given)")
-
-    batched_rev = rev_lut.ndim == 2
-    ev_spec = pl.BlockSpec((1, n_events), lambda b: (b, 0))
-    if batched_rev:
-        rev_spec = pl.BlockSpec((1, rev_lut.shape[1]), lambda b: (b, 0))
-    else:
-        rev_spec = pl.BlockSpec(rev_lut.shape, lambda b: (0,))
-    out_spec = pl.BlockSpec((1, capacity), lambda b: (b, 0))
-    drop_spec = pl.BlockSpec((1, 1), lambda b: (b, 0))
-
-    kernel = functools.partial(_merge_pack_kernel, capacity=capacity,
-                               batched_rev=batched_rev,
-                               n_segments=n_segments, wire16=wire16,
-                               queue=queue)
-    if times is None:
-        in_specs = [ev_spec, ev_spec, rev_spec]
-        out_specs = (out_spec, out_spec, drop_spec)
-        out_shape = (
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-        )
-        operands = (labels, valid, rev_lut)
-    else:
-        in_specs = [ev_spec, ev_spec, ev_spec, rev_spec]
-        out_specs = (out_spec, out_spec, out_spec, drop_spec)
-        out_shape = (
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, capacity), jnp.int32),
-            jax.ShapeDtypeStruct((batch, 1), jnp.int32),
-        )
-        operands = (labels, valid, times.astype(jnp.int32), rev_lut)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*operands)
+    packed = _pack_call(valid, labels, capacity=capacity, interpret=interpret,
+                        times=times, queue=queue,
+                        wire16=labels.dtype == jnp.int16)
+    out_l, out_v = _rev_lookup(rev_lut, packed[0], packed[-2])
+    dropped = packed[-1][:, None]
+    if queue is None:
+        return out_l, out_v, dropped
+    return out_l, out_v, jnp.where(out_v == 1, packed[1], 0), dropped

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.analysis import roofline as rl
 from repro.configs import ARCH_NAMES, get_config
 from repro.configs.base import ModelConfig
@@ -153,7 +152,7 @@ def _cell_costs(cfg: ModelConfig, shape_name: str, mesh,
     with mesh, shardlib.activation_shardings(mesh):
         compiled = jax.jit(fn, in_shardings=shardings,
                            donate_argnums=donate).lower(*args).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),
             "coll": float(total_collective_bytes(compiled.as_text()))}
@@ -218,7 +217,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     print(f"[{cell_id}] memory_analysis: {mem}")
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     print(f"[{cell_id}] cost_analysis (scanned, loop bodies ×1): "
           f"flops={cost.get('flops', 0):.3e} "
           f"bytes={cost.get('bytes accessed', 0):.3e}")

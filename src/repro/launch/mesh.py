@@ -12,13 +12,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh as _make_mesh
+from repro.parallel.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2,
@@ -26,5 +26,5 @@ def make_debug_mesh(data: int = 2, model: int = 2,
     """Small mesh for CPU tests (requires xla_force_host_platform_device_count
     ≥ data·model·pod)."""
     if pod:
-        return _make_mesh((pod, data, model), ("pod", "data", "model"))
-    return _make_mesh((data, model), ("data", "model"))
+        return auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

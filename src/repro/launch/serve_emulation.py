@@ -22,6 +22,7 @@ import time
 import numpy as np
 
 from repro.analysis import scenarios as scen
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.engine import EmulationEngine
 
 
@@ -48,6 +49,7 @@ def main():
                     help="reduced per-chip array (32 neurons x 16 rows)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     chip = None
     if args.small:

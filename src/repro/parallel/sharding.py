@@ -27,7 +27,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import Param, is_param
@@ -159,6 +159,13 @@ def replicated(mesh: Mesh):
 # axis-name flags; ``fabric.FabricInterconnect`` consumes the same names.
 
 
+def auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the sharding
+    rules here place data by ``PartitionSpec`` and let the compiler
+    propagate it (jax 0.9 defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def fabric_axis_names(plan) -> tuple[str, ...]:
     """Mesh axis names for a fabric plan, leaf level first: fab0, fab1, ..."""
     return tuple(f"fab{i}" for i in range(plan.n_levels))
@@ -168,11 +175,9 @@ def fabric_mesh(plan) -> Mesh:
     """Nested device mesh for a ``fabric.FabricPlan``: one axis per level,
     top level outermost (needs ``plan.n_nodes`` devices — use
     ``xla_force_host_platform_device_count`` for CPU tests)."""
-    from repro.compat import make_mesh
-
     names = fabric_axis_names(plan)
     shape = tuple(lvl.fan_in for lvl in reversed(plan.levels))
-    return make_mesh(shape, tuple(reversed(names)))
+    return auto_mesh(shape, tuple(reversed(names)))
 
 
 def fabric_leaf_index(axis_names: tuple, fan_ins: tuple) -> jax.Array:

@@ -175,7 +175,7 @@ class EmulationEngine:
                 return jnp.where(sel.reshape(shape), fresh, cur)
             return pick
 
-        def _step(state, plast, stim, cursor, mask, reset):
+        def _step(params, state, plast, stim, cursor, mask, reset):
             # Freshly admitted slots start from the init row; folding the
             # reset in here (one select over the state) keeps admission
             # O(state) per window instead of O(state) per admitted session.
@@ -246,7 +246,10 @@ class EmulationEngine:
             return (netlib.NetworkState(chips=chips, inflight=inflight),
                     row_plast)
 
-        self._step_fn = jax.jit(_step)
+        # The window program.  The network parameters are an argument, not a
+        # closure constant: at the published chip size they are ~50 MB per
+        # fabric, which would otherwise be baked into the compiled program.
+        self.window_fn = jax.jit(_step)
         self._insert_fn = jax.jit(_insert)
         self._extract_fn = jax.jit(_extract)
 
@@ -339,8 +342,8 @@ class EmulationEngine:
         remaining = np.where(occ, self._length - self._cursor, 0)
         mask = (np.arange(self.window)[:, None] < remaining[None, :])
         reset = self._pending_reset.copy()
-        self._state, self._plast, payload = self._step_fn(
-            self._state, self._plast, jnp.asarray(self._stim),
+        self._state, self._plast, payload = self.window_fn(
+            self.params, self._state, self._plast, jnp.asarray(self._stim),
             jnp.asarray(self._cursor), jnp.asarray(mask),
             jnp.asarray(reset))
         # Only the resets this call materialized — _admit below may flag
@@ -358,16 +361,20 @@ class EmulationEngine:
         self._admit()
         return finished
 
+    def window_args(self) -> tuple:
+        """Arguments of an all-masked call of ``window_fn`` on the real
+        shapes — what ``warm`` runs; ``window_fn.lower(*window_args())`` is
+        the window program for inspection or ahead-of-time compilation."""
+        return (self.params, self._state, self._plast,
+                jnp.asarray(self._stim), jnp.asarray(self._cursor),
+                jnp.zeros((self.window, self.slots), bool),
+                jnp.zeros((self.slots,), bool))
+
     def warm(self) -> None:
         """Compile the window program on the real shapes without advancing
         any session (all-masked step; the returned state is discarded) —
         call before timing so the clock never includes jit compilation."""
-        mask = jnp.zeros((self.window, self.slots), bool)
-        out = self._step_fn(self._state, self._plast,
-                            jnp.asarray(self._stim),
-                            jnp.asarray(self._cursor), mask,
-                            jnp.zeros((self.slots,), bool))
-        jax.block_until_ready(out[0])
+        jax.block_until_ready(self.window_fn(*self.window_args())[0])
 
     def _account(self, payload, remaining) -> None:
         if self.keep_spikes:

@@ -125,7 +125,7 @@ def test_compiled_exchange_gather_bytes_match_wire_model():
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, timeout=600,
                          env={**__import__("os").environ,
-                              "PYTHONPATH": "src"})
+                              "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     assert res.returncode == 0, f"stderr:\n{res.stderr[-2000:]}"
     got = json.loads(res.stdout.strip().splitlines()[-1])
     # per-partition program: one gather per level, bytes within [model, 2x]
@@ -311,7 +311,7 @@ def test_f64_leak_flagged():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.zeros(3, jnp.float32))
     diags = jaxprlint.check_f64(closed, "prog")
@@ -503,6 +503,41 @@ def test_overlapping_grid_tiling_flagged():
     diags = kernelcheck.check_pallas_calls(
         bad, (jnp.zeros((2, 4), jnp.float32),), "bad")
     assert "kernel.grid-overlap" in checks(diags)
+
+
+@pytest.mark.parametrize("semantics, clean", [
+    (("parallel", "arbitrary"), True),     # revisit along a reduction axis
+    (("arbitrary", "parallel"), False),    # revisit along a parallel axis
+])
+def test_accumulator_block_needs_a_sequential_axis(semantics, clean):
+    """An output block that consecutive steps of a declared-sequential grid
+    axis revisit is a resident accumulator; the same revisit along a
+    parallel axis is an overwrite."""
+    import jax
+    import jax.experimental.pallas as pl
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    def row_sums(x):
+        def kernel(x_ref, o_ref):
+            @pl.when(pl.program_id(1) == 0)
+            def _():
+                o_ref[...] = jnp.zeros_like(o_ref)
+            o_ref[...] += x_ref[...]
+        return pl.pallas_call(
+            kernel, grid=(2, 3),
+            in_specs=[pl.BlockSpec((1, 4), lambda i, k: (i, k))],
+            out_specs=pl.BlockSpec((1, 4), lambda i, k: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((2, 4), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=semantics),
+            interpret=True)(x)
+
+    diags = kernelcheck.check_pallas_calls(
+        row_sums, (jnp.zeros((2, 12), jnp.float32),), "acc")
+    assert (diags == []) is clean
+    if not clean:
+        assert "kernel.grid-overlap" in checks(diags)
 
 
 def test_out_of_bounds_grid_tiling_flagged():

@@ -7,6 +7,8 @@ the pure-jnp oracle (``mode="jax"``) and the Pallas interpreter
 
     op         ∈ {exchange_fwd, merge_pack_fwd, exchange_stream_fwd}
     occupancy  ∈ {0 %, 2 %, 50 %, 100 %}
+    stream     ∈ {one tile, three}    (merge_pack only — the kernel's
+                                       128-event tiles)
     wire16     ∈ {off, on}            (merge_pack only)
     pack       ∈ {global, segmented}  (merge_pack only)
     timed      ∈ {off, on}            (merge_pack only — the timestamp lane)
@@ -127,11 +129,25 @@ def test_exchange_stream_conformance(occupancy):
 @pytest.mark.parametrize("segmented", [False, True])
 @pytest.mark.parametrize("timed", [False, True])
 def test_merge_pack_conformance(occupancy, wire16, segmented, timed):
+    _check_merge_pack(occupancy, wire16, segmented, timed, 2 * CAP_IN)
+
+
+@pytest.mark.parametrize("occupancy", OCCUPANCIES)
+@pytest.mark.parametrize("wire16", [False, True])
+@pytest.mark.parametrize("segmented", [False, True])
+@pytest.mark.parametrize("timed", [False, True])
+def test_merge_pack_conformance_multi_tile(occupancy, wire16, segmented,
+                                           timed):
+    """336 events span three of the kernel's 128-event tiles, so the
+    running rank base between tiles is exercised."""
+    _check_merge_pack(occupancy, wire16, segmented, timed, 14 * CAP_IN)
+
+
+def _check_merge_pack(occupancy, wire16, segmented, timed, n_events):
     batch = N_SRC
-    n_events = 2 * CAP_IN
     key = jax.random.fold_in(
         KEY, 1000 + int(occupancy * 100) + 7 * wire16 + 13 * segmented
-        + 29 * timed)
+        + 29 * timed + (n_events - 2 * CAP_IN))
     state = identity_router(batch)
     labels, valid = _frames(key, (batch, n_events), occupancy)
     times = jnp.where(valid,

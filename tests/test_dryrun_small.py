@@ -9,10 +9,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro import compat
 from repro.analysis import hlo as hlolib
 from repro.configs import ARCH_NAMES, get_config, smoke_config
 from repro.launch.shapes import SHAPES, cell_supported, input_specs
+from repro.parallel.sharding import auto_mesh
 
 
 def test_cell_support_matrix():
@@ -62,7 +62,7 @@ def test_smoke_cell_lowers_and_compiles():
     from repro.launch import dryrun
 
     cfg = smoke_config(get_config("qwen3-8b"))
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     # Reuse build_cell with a smoke config by monkey-building inputs.
     import jax.numpy as jnp
     from repro.models import model as M
@@ -82,7 +82,7 @@ def test_smoke_cell_lowers_and_compiles():
             pshard, {"tokens": shardlib.data_sharding_if_divisible(
                 mesh, (2, 17))})).lower(params, batch).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     assert cost.get("flops", 0) > 0
 
 

@@ -139,7 +139,8 @@ def test_queue_wait_monotone_in_rate(r1, r2):
     n = 512
 
     def mean_wait(rate_hz):
-        arrivals = jnp.arange(n) * (1e9 / (3.0 * rate_hz))   # 3:1 fan-in
+        arrivals = (jnp.arange(n, dtype=jnp.float32)
+                    * (1e9 / (3.0 * rate_hz)))        # 3:1 fan-in
         return float(jnp.mean(_lindley_queue(
             arrivals, MGT_CLOCK_NS, DEFAULT_PARAMS.cc_interval,
             DEFAULT_PARAMS.cc_stall_ns)))

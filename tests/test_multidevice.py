@@ -17,12 +17,12 @@ def _run(body: str) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro import compat
+        from repro.parallel.sharding import auto_mesh
     """) + textwrap.dedent(body)
     res = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                          text=True, timeout=600,
                          env={**__import__("os").environ,
-                              "PYTHONPATH": "src"})
+                              "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
     assert res.returncode == 0, f"stderr:\n{res.stderr[-2000:]}"
     return res.stdout
 
@@ -30,7 +30,7 @@ def _run(body: str) -> str:
 def test_hierarchical_psum_equals_flat():
     out = _run("""
         from repro.parallel.collectives import hierarchical_psum
-        mesh = compat.make_mesh((2, 4), ("pod", "data"))
+        mesh = auto_mesh((2, 4), ("pod", "data"))
         x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
 
         def flat(v):
@@ -40,9 +40,9 @@ def test_hierarchical_psum_equals_flat():
             return hierarchical_psum(v, "data", "pod")
 
         spec = P(("pod", "data"))
-        f = jax.jit(compat.shard_map(flat, mesh=mesh, in_specs=spec,
+        f = jax.jit(jax.shard_map(flat, mesh=mesh, in_specs=spec,
                                   out_specs=spec))
-        h = jax.jit(compat.shard_map(hier, mesh=mesh, in_specs=spec,
+        h = jax.jit(jax.shard_map(hier, mesh=mesh, in_specs=spec,
                                   out_specs=spec))
         print("MATCH", bool(jnp.allclose(f(x), h(x))))
     """)
@@ -52,7 +52,7 @@ def test_hierarchical_psum_equals_flat():
 def test_star_exchange_on_8_chips():
     out = _run("""
         from repro.core import StarInterconnect, identity_router, make_frame
-        mesh = compat.make_mesh((8,), ("chip",))
+        mesh = auto_mesh((8,), ("chip",))
         ic = StarInterconnect(mesh, "chip", capacity=64)
         fn = ic.exchange_fn()
         st = identity_router(8)
@@ -72,7 +72,7 @@ def test_stream_fn_matches_per_step_exchange_on_8_chips():
     """The scanned shard_map stream equals T per-step exchange dispatches."""
     out = _run("""
         from repro.core import StarInterconnect, identity_router, make_frame
-        mesh = compat.make_mesh((8,), ("chip",))
+        mesh = auto_mesh((8,), ("chip",))
         ic = StarInterconnect(mesh, "chip", capacity=32)
         st = identity_router(8)
         key = jax.random.key(0)
@@ -115,7 +115,7 @@ def test_hierarchical_stacked_matches_shard_map():
         valid = jax.random.uniform(jax.random.fold_in(key, 2),
                                    (T, N, 16)) < 0.7
         frames, _ = make_frame(labels, None, valid, 16)
-        mesh = compat.make_mesh((n_pods, per), ("pod", "chip"))
+        mesh = auto_mesh((n_pods, per), ("pod", "chip"))
         ok = True
         for caps in (dict(), dict(link_capacity=12, pod_capacity=24)):
             ic = StarInterconnect(mesh, "chip", pod_axis="pod", capacity=24,
@@ -159,7 +159,7 @@ def test_timed_exchange_stacked_matches_shard_map():
         # Star on 8 chips vs the stacked timed round (full enables incl.
         # self-loops so both sides see identical routes).
         en = jnp.ones((N, N), bool)
-        mesh = compat.make_mesh((N,), ("chip",))
+        mesh = auto_mesh((N,), ("chip",))
         ic = StarInterconnect(mesh, "chip", capacity=32, timing=w)
         out_s, d_s = ic.exchange_fn()(frames, st.fwd_tables, st.rev_tables,
                                       en)
@@ -181,7 +181,7 @@ def test_timed_exchange_stacked_matches_shard_map():
         # compact-before-gather uplink stages on.
         n_pods, per = 2, 4
         intra, inter = full_route_enables(per), full_route_enables(n_pods)
-        mesh2 = compat.make_mesh((n_pods, per), ("pod", "chip"))
+        mesh2 = auto_mesh((n_pods, per), ("pod", "chip"))
         for caps in (dict(), dict(link_capacity=12, pod_capacity=40)):
             ic2 = StarInterconnect(mesh2, "chip", pod_axis="pod",
                                    capacity=32, timing=w, **caps)
@@ -451,10 +451,10 @@ def test_engine_batched_step_shards_over_slot_axis():
 
         ref = step(state, plast, drives, mask)
 
-        mesh = compat.make_mesh((8,), ("slot",))
+        mesh = auto_mesh((8,), ("slot",))
         state_specs = netlib.NetworkState(chips=P(None, "slot"),
                                           inflight=P(None, None, "slot"))
-        sharded = jax.jit(compat.shard_map(
+        sharded = jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(state_specs, P(None, "slot"), P(None, None, "slot"),
                       P(None, "slot")),
@@ -482,7 +482,7 @@ def test_sharded_train_step_matches_single_device():
                                               cfg.vocab_size)}
         base, _ = M.train_loss(params, batch, cfg)
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         pshard = shardlib.param_shardings(params, mesh)
         params_s = jax.device_put(params, pshard)
         batch_s = jax.device_put(batch, {"tokens": NamedSharding(
@@ -512,7 +512,7 @@ def test_elastic_reshard_on_load():
         shutil.rmtree("/tmp/repro_elastic_test", ignore_errors=True)
         ckpt.save("/tmp/repro_elastic_test", 3, state)
 
-        mesh = compat.make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         restored, manifest = resume_on_mesh("/tmp/repro_elastic_test", state,
                                             mesh)
         leaf = jax.tree.leaves(restored["params"])[0]

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.ckpt import checkpoint as ckpt
 from repro.configs import get_config, smoke_config
 from repro.data.pipeline import DataConfig, synthetic_batch
@@ -169,14 +168,14 @@ def test_int8_quantization_error_bounded():
 
 def test_resolve_spec_divisibility_fallback():
     import os
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = shardlib.auto_mesh((1,), ("model",))
     # dim divisible by 1 → sharded on model
     spec = shardlib.resolve_spec(("vocab", "embed"), (100, 64), mesh)
     assert spec[0] == "model"
 
 
 def test_resolve_spec_conflict_first_wins():
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = shardlib.auto_mesh((1,), ("model",))
     # experts and ff both want 'model'; experts (first) wins
     spec = shardlib.resolve_spec(("experts", "embed", "ff"), (8, 64, 128),
                                  mesh)
@@ -187,7 +186,7 @@ def test_param_shardings_cover_tree():
     cfg = smoke_config(get_config("qwen3-8b"))
     from repro.models import model as M
     params = jax.eval_shape(lambda: M.init_params(jax.random.key(0), cfg))
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = shardlib.auto_mesh((1,), ("model",))
     shardings = shardlib.param_shardings(params, mesh)
     n_params = len(jax.tree.leaves(params))
     n_shards = len(jax.tree.leaves(

@@ -22,7 +22,6 @@ except ImportError:          # property tests skip; plain tests still run
 
     st = _AnyStrategy()
 
-from repro import compat  # noqa: E402
 from repro.core import (DEFAULT_PARAMS, LINK_BANDWIDTH_OPTIMIZED,  # noqa: E402
                         LINK_LATENCY_OPTIMIZED, PROJECTED_120CHIP, SyncConfig,
                         barrier_release_time, biological_latency_ms,
@@ -31,6 +30,7 @@ from repro.core import (DEFAULT_PARAMS, LINK_BANDWIDTH_OPTIMIZED,  # noqa: E402
                         lookup_rev, make_frame, pack_words, route_step,
                         simulate_fan_in, unpack_words)
 from repro.core.events import SPIKES_PER_WORD
+from repro.parallel.sharding import auto_mesh
 
 KEY = jax.random.key(0)
 
@@ -245,8 +245,8 @@ def test_barrier_timeout_recovery():
 def test_barrier_in_graph():
     from repro.core.sync import barrier
 
-    mesh = compat.make_mesh((1,), ("chip",))
-    fn = jax.jit(compat.shard_map(
+    mesh = auto_mesh((1,), ("chip",))
+    fn = jax.jit(jax.shard_map(
         lambda r: barrier(r[0], "chip")[None],
         mesh=mesh, in_specs=jax.sharding.PartitionSpec("chip"),
         out_specs=jax.sharding.PartitionSpec("chip")))
