@@ -980,6 +980,11 @@ def fabric_route_step(state, frames: EventFrame, plan: FabricPlan, *,
       (ingress frames [n_nodes, capacity],
        ExchangeDrops(congestion, uplink, unroutable, rerouted), each
        int32[n_nodes]).
+
+    The leaf's forward lookup and uplink pack run under the named scope
+    ``leaf``, each level's pass under ``level<i>`` and the destination
+    merge under ``merge``, so a profiler trace splits the exchange's device
+    time by level.
     """
     if use_fused is None:
         use_fused = fused_exchange_enabled()
@@ -1012,22 +1017,24 @@ def fabric_route_step(state, frames: EventFrame, plan: FabricPlan, *,
         return ingress, ExchangeDrops(congestion=dropped, uplink=zeros,
                                       unroutable=zeros, rerouted=zeros)
 
-    wire, fwd_en = jax.vmap(routing.lookup_fwd)(state.fwd_tables,
-                                                frames.labels)
-    ev = frames.valid & fwd_en                             # [n, cap_in]
-    times = (_egress_times(frames.times, ev, timing)
-             if timing is not None else None)
+    # The leaf's forward lookup and its uplink pack.
+    with jax.named_scope("leaf"):
+        wire, fwd_en = jax.vmap(routing.lookup_fwd)(state.fwd_tables,
+                                                    frames.labels)
+        ev = frames.valid & fwd_en                             # [n, cap_in]
+        times = (_egress_times(frames.times, ev, timing)
+                 if timing is not None else None)
 
-    # Leaf uplink — pack each leaf's egress to its MGT lane capacity.
-    u0 = levels[0].link_capacity
-    if u0 is not None:
-        packed, link_drop = make_frame(wire, times, ev, u0)
-        wire, ev = packed.labels, packed.valid             # [n, u0]
-        if timing is not None:
-            times = packed.times
-    else:
-        link_drop = jnp.zeros((n,), jnp.int32)
-    uplink = link_drop.astype(jnp.int32)
+        # Leaf uplink — pack each leaf's egress to its MGT lane capacity.
+        u0 = levels[0].link_capacity
+        if u0 is not None:
+            packed, link_drop = make_frame(wire, times, ev, u0)
+            wire, ev = packed.labels, packed.valid             # [n, u0]
+            if timing is not None:
+                times = packed.times
+        else:
+            link_drop = jnp.zeros((n,), jnp.int32)
+        uplink = link_drop.astype(jnp.int32)
 
     layout = plan.merge_layout(cap_in)
     leaf = jnp.arange(n)
@@ -1040,165 +1047,175 @@ def fabric_route_step(state, frames: EventFrame, plan: FabricPlan, *,
     recv_ok = None                            # per-leaf downlink path health
     parts_l, parts_v, parts_t, seg_lens = [], [], [], []
     for i, lvl in enumerate(levels):
-        f = lvl.fan_in
-        gnext = gsize * f
-        n_grp = n // gnext
-        ent = leaf // gsize                   # each leaf's entity at this level
+        with jax.named_scope(f"level{i}"):
+            f = lvl.fan_in
+            gnext = gsize * f
+            n_grp = n // gnext
+            ent = leaf // gsize          # each leaf's entity at this level
 
-        # Degraded mode — uplink health gates the tier-i entity streams
-        # before they join this merge (and before they cascade upward):
-        # detoured streams keep their merge slot (the host relays the same
-        # wire content, so delivery is bit-exact) but pay the detour on the
-        # timed lane; streams with no surviving route are masked and their
-        # events counted unroutable, attributed to every leaf of the subtree.
-        dyn_up = None if health is None else health.uplink[i]
-        flow_ok, live_detour = _flow_masks(lvl, dyn_up, n // gsize)
-        if flow_ok is not None:
-            counts = cur_v.sum(axis=-1).astype(jnp.int32)
-            if timing is not None:
-                pen = _detour_penalty(lvl, timing, cur_v)
-                cur_t = jnp.where(live_detour[:, None] & cur_v,
-                                  cur_t + pen, cur_t)
-            cur_v = cur_v & flow_ok[:, None]
-            unroutable = unroutable + jnp.where(flow_ok, 0, counts)[ent]
-            rerouted = rerouted + jnp.where(live_detour, counts, 0)[ent]
-        # Downlink health accumulates along each leaf's descent path: the
-        # level-i part reaches a destination through its downlinks at
-        # levels i..1, so a dead edge kills this and every higher part.
-        dyn_down = None if health is None else health.downlink[i]
-        d_ok = _down_mask(lvl, dyn_down, ent)
-        if d_ok is not None:
-            recv_ok = d_ok if recv_ok is None else recv_ok & d_ok
+            # Degraded mode — uplink health gates the tier-i entity streams
+            # before they join this merge (and before they cascade upward):
+            # detoured streams keep their merge slot (the host relays the
+            # same wire content, so delivery is bit-exact) but pay the detour
+            # on the timed lane; streams with no surviving route are masked
+            # and their events counted unroutable, attributed to every leaf
+            # of the subtree.
+            dyn_up = None if health is None else health.uplink[i]
+            flow_ok, live_detour = _flow_masks(lvl, dyn_up, n // gsize)
+            if flow_ok is not None:
+                counts = cur_v.sum(axis=-1).astype(jnp.int32)
+                if timing is not None:
+                    pen = _detour_penalty(lvl, timing, cur_v)
+                    cur_t = jnp.where(live_detour[:, None] & cur_v,
+                                      cur_t + pen, cur_t)
+                cur_v = cur_v & flow_ok[:, None]
+                unroutable = unroutable + jnp.where(flow_ok, 0, counts)[ent]
+                rerouted = rerouted + jnp.where(live_detour, counts, 0)[ent]
+            # Downlink health accumulates along each leaf's descent path:
+            # the level-i part reaches a destination through its downlinks at
+            # levels i..1, so a dead edge kills this and every higher part.
+            dyn_down = None if health is None else health.downlink[i]
+            d_ok = _down_mask(lvl, dyn_down, ent)
+            if d_ok is not None:
+                recv_ok = d_ok if recv_ok is None else recv_ok & d_ok
 
-        s_len = f * cur_len
-        anc = leaf // gnext                   # tier-(i+1) ancestor of each leaf
-        if routed:
-            # Routed mode: only the hop-graph edges enter the merge — each
-            # destination selects its enabled source entities' streams via a
-            # static per-level schedule (padded to the max in-degree with
-            # all-invalid segments), so the own subtree and route-disabled
-            # pairs cost no merge work instead of riding along gated-off.
-            # The selection moves int16 wire words (validity rides the
-            # embedded bit; the enable lane is a static constant) and keeps
-            # ascending source order, matching the gather layout — the
-            # surviving valid-event sequence, and with it labels/valids/
-            # drops/timestamps, is bit-exact.
-            src_flat, live, deg = _routed_leaf_maps(lvl.enables, i, n,
-                                                    gsize, f)
-            n_ent = n_grp * f
-            sel = pack_wire16(cur_l, cur_v).reshape(n_grp, f, cur_len)
-            sel = sel[:, src_flat].reshape(n_ent, deg * cur_len)
-            # Entity → leaf expansion is a contiguous repeat (leaves of one
-            # entity are adjacent), so it lowers to broadcast+reshape — a
-            # copy loop, never a gather chain XLA would re-evaluate
-            # element-wise inside the merge fusion.
-            part_l = _repeat_rows(sel, n // n_ent)
-            part_v = jnp.broadcast_to(
-                live[:, :, None], (n, deg, cur_len)).reshape(n, deg * cur_len)
-            per_child = layout[i][:len(layout[i]) // f]
-            level_segs = list(per_child) * deg
-        else:
-            # S_i per tier-(i+1) entity: the concat of its children's U_i.
-            s_l = cur_l.reshape(n_grp, s_len)
-            s_v = cur_v.reshape(n_grp, f, cur_len)
-            child = ent % f                   # leaf's child slot at this level
-            gate = lvl.enables.T[child]       # [n, f] src child → this dest
-            if i > 0:
-                gate = gate & (jnp.arange(f)[None, :] != child[:, None])
-            if n_grp == 1:
-                # Top-of-tree streams stay shared views (the hardware
-                # broadcasts a wire, not a buffer); only validity is
-                # per-destination.
-                part_l = jnp.broadcast_to(s_l.reshape(1, s_len), (n, s_len))
-                part_v = (s_v[0][None] & gate[:, :, None]).reshape(n, s_len)
-            else:
-                part_l = s_l[anc]
-                part_v = (s_v[anc] & gate[:, :, None]).reshape(n, s_len)
-            level_segs = list(layout[i])
-        if recv_ok is not None:
+            s_len = f * cur_len
+            anc = leaf // gnext          # tier-(i+1) ancestor of each leaf
             if routed:
-                # The enable lane is slots, not events — count the embedded
-                # valid bits for the loss attribution, like the sharded path.
-                _, w_v = unpack_wire16(part_l)
-                lost = (w_v & part_v).sum(axis=-1).astype(jnp.int32)
+                # Routed mode: only the hop-graph edges enter the merge —
+                # each destination selects its enabled source entities'
+                # streams via a static per-level schedule (padded to the max
+                # in-degree with all-invalid segments), so the own subtree and
+                # route-disabled pairs cost no merge work instead of riding
+                # along gated-off.  The selection moves int16 wire words
+                # (validity rides the embedded bit; the enable lane is a
+                # static constant) and keeps ascending source order, matching
+                # the gather layout — the surviving valid-event sequence, and
+                # with it labels/valids/drops/timestamps, is bit-exact.
+                src_flat, live, deg = _routed_leaf_maps(lvl.enables, i, n,
+                                                        gsize, f)
+                n_ent = n_grp * f
+                sel = pack_wire16(cur_l, cur_v).reshape(n_grp, f, cur_len)
+                sel = sel[:, src_flat].reshape(n_ent, deg * cur_len)
+                # Entity → leaf expansion is a contiguous repeat (leaves of
+                # one entity are adjacent), so it lowers to broadcast+reshape
+                # — a copy loop, never a gather chain XLA would re-evaluate
+                # element-wise inside the merge fusion.
+                part_l = _repeat_rows(sel, n // n_ent)
+                part_v = jnp.broadcast_to(
+                    live[:, :, None],
+                    (n, deg, cur_len)).reshape(n, deg * cur_len)
+                per_child = layout[i][:len(layout[i]) // f]
+                level_segs = list(per_child) * deg
             else:
-                lost = part_v.sum(axis=-1).astype(jnp.int32)
-            part_v = part_v & recv_ok[:, None]
-            unroutable = unroutable + jnp.where(recv_ok, 0, lost)
-        parts_l.append(part_l)
-        parts_v.append(part_v)
-        if timing is not None:
-            if routed:
-                sel_t = cur_t.reshape(n_grp, f, cur_len)
-                sel_t = sel_t[:, src_flat].reshape(n_ent, deg * cur_len)
-                parts_t.append(_repeat_rows(sel_t, n // n_ent))
-            else:
-                s_t = cur_t.reshape(n_grp, s_len)
-                parts_t.append(
-                    jnp.broadcast_to(s_t.reshape(1, s_len), (n, s_len))
-                    if n_grp == 1 else s_t[anc])
-        seg_lens += level_segs
-
-        if i + 1 < len(levels):
-            # Prepare U_{i+1}: each tier-(i+1) entity uplinks its aggregated
-            # stream into the next level's merge — timed events pay the
-            # crossing extra plus the wait of their rank in the stream, and
-            # the pack cascades (an event crossing k levels must survive
-            # every intermediate uplink).  The cascade is ungated — it
-            # aggregates whole entity streams — so routed mode feeds it the
-            # same full concatenation as gather.
-            nxt = levels[i + 1]
-            s_l = cur_l.reshape(n_grp, s_len)
-            s_vf = cur_v.reshape(n_grp, s_len)
+                # S_i per tier-(i+1) entity: the concat of its children's U_i.
+                s_l = cur_l.reshape(n_grp, s_len)
+                s_v = cur_v.reshape(n_grp, f, cur_len)
+                child = ent % f               # leaf's child slot at this level
+                gate = lvl.enables.T[child]   # [n, f] src child → this dest
+                if i > 0:
+                    gate = gate & (jnp.arange(f)[None, :] != child[:, None])
+                if n_grp == 1:
+                    # Top-of-tree streams stay shared views (the hardware
+                    # broadcasts a wire, not a buffer); only validity is
+                    # per-destination.
+                    part_l = jnp.broadcast_to(s_l.reshape(1, s_len),
+                                              (n, s_len))
+                    part_v = (s_v[0][None]
+                              & gate[:, :, None]).reshape(n, s_len)
+                else:
+                    part_l = s_l[anc]
+                    part_v = (s_v[anc] & gate[:, :, None]).reshape(n, s_len)
+                level_segs = list(layout[i])
+            if recv_ok is not None:
+                if routed:
+                    # The enable lane is slots, not events — count the
+                    # embedded valid bits for the loss attribution, like the
+                    # sharded path.
+                    _, w_v = unpack_wire16(part_l)
+                    lost = (w_v & part_v).sum(axis=-1).astype(jnp.int32)
+                else:
+                    lost = part_v.sum(axis=-1).astype(jnp.int32)
+                part_v = part_v & recv_ok[:, None]
+                unroutable = unroutable + jnp.where(recv_ok, 0, lost)
+            parts_l.append(part_l)
+            parts_v.append(part_v)
             if timing is not None:
-                okp = s_vf.astype(jnp.int32)
-                prank = jnp.cumsum(okp, axis=-1) - okp
-                extra = (nxt.extra_ns if nxt.extra_ns is not None
-                         else timing.second_layer_extra_ns)
-                s_t = jnp.where(
-                    s_vf, cur_t.reshape(n_grp, s_len) + extra
-                    + _queue_wait_i32(prank, timing.uplink_queue), 0)
-            else:
-                s_t = None
-            if nxt.link_capacity is not None:
-                up, drop = make_frame(s_l, s_t, s_vf, nxt.link_capacity)
-                cur_l, cur_v = up.labels, up.valid
-                cur_t = up.times if timing is not None else None
-                cur_len = nxt.link_capacity
-                uplink = uplink + drop[anc].astype(jnp.int32)
-            else:
-                cur_l, cur_v, cur_t = s_l, s_vf, s_t
-                cur_len = s_len
-            gsize = gnext
+                if routed:
+                    sel_t = cur_t.reshape(n_grp, f, cur_len)
+                    sel_t = sel_t[:, src_flat].reshape(n_ent, deg * cur_len)
+                    parts_t.append(_repeat_rows(sel_t, n // n_ent))
+                else:
+                    s_t = cur_t.reshape(n_grp, s_len)
+                    parts_t.append(
+                        jnp.broadcast_to(s_t.reshape(1, s_len), (n, s_len))
+                        if n_grp == 1 else s_t[anc])
+            seg_lens += level_segs
 
-    labels = jnp.concatenate(parts_l, axis=-1)
-    valid = jnp.concatenate(parts_v, axis=-1)
-    merge_times = (jnp.concatenate(parts_t, axis=-1)
-                   if timing is not None else None)
-    seg_lens = tuple(seg_lens)
-    if routed and not (use_fused or timing is not None):
-        # The plain-pack fallback wants unpacked labels; the fused/timed
-        # merges take the int16 wire words (embedded valid & enable lane)
-        # directly, like the sharded executor.
-        w_l, w_v = unpack_wire16(labels)
-        labels, valid = w_l, w_v & valid
-    if use_fused or timing is not None:
-        ingress, dropped = _fused_merge(labels, valid, state.rev_tables,
-                                        plan.capacity, seg_lens=seg_lens,
-                                        compact=plan.compact, timing=timing,
-                                        use_fused=use_fused,
-                                        times=merge_times)
+            if i + 1 < len(levels):
+                # Prepare U_{i+1}: each tier-(i+1) entity uplinks its
+                # aggregated stream into the next level's merge — timed
+                # events pay the crossing extra plus the wait of their rank
+                # in the stream, and the pack cascades (an event crossing k
+                # levels must survive every intermediate uplink).  The
+                # cascade is ungated — it aggregates whole entity streams —
+                # so routed mode feeds it the same full concatenation as
+                # gather.
+                nxt = levels[i + 1]
+                s_l = cur_l.reshape(n_grp, s_len)
+                s_vf = cur_v.reshape(n_grp, s_len)
+                if timing is not None:
+                    okp = s_vf.astype(jnp.int32)
+                    prank = jnp.cumsum(okp, axis=-1) - okp
+                    extra = (nxt.extra_ns if nxt.extra_ns is not None
+                             else timing.second_layer_extra_ns)
+                    s_t = jnp.where(
+                        s_vf, cur_t.reshape(n_grp, s_len) + extra
+                        + _queue_wait_i32(prank, timing.uplink_queue), 0)
+                else:
+                    s_t = None
+                if nxt.link_capacity is not None:
+                    up, drop = make_frame(s_l, s_t, s_vf, nxt.link_capacity)
+                    cur_l, cur_v = up.labels, up.valid
+                    cur_t = up.times if timing is not None else None
+                    cur_len = nxt.link_capacity
+                    uplink = uplink + drop[anc].astype(jnp.int32)
+                else:
+                    cur_l, cur_v, cur_t = s_l, s_vf, s_t
+                    cur_len = s_len
+                gsize = gnext
+
+    # The destination merge of every level's parts.
+    with jax.named_scope("merge"):
+        labels = jnp.concatenate(parts_l, axis=-1)
+        valid = jnp.concatenate(parts_v, axis=-1)
+        merge_times = (jnp.concatenate(parts_t, axis=-1)
+                       if timing is not None else None)
+        seg_lens = tuple(seg_lens)
+        if routed and not (use_fused or timing is not None):
+            # The plain-pack fallback wants unpacked labels; the fused/timed
+            # merges take the int16 wire words (embedded valid & enable lane)
+            # directly, like the sharded executor.
+            w_l, w_v = unpack_wire16(labels)
+            labels, valid = w_l, w_v & valid
+        if use_fused or timing is not None:
+            ingress, dropped = _fused_merge(
+                labels, valid, state.rev_tables, plan.capacity,
+                seg_lens=seg_lens, compact=plan.compact, timing=timing,
+                use_fused=use_fused, times=merge_times)
+            return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
+                                          unroutable=unroutable,
+                                          rerouted=rerouted)
+        mixed, dropped = make_frame_segmented(labels, None, valid,
+                                              plan.capacity, seg_lens,
+                                              compact=plan.compact)
+        chip, rev_en = jax.vmap(routing.lookup_rev)(state.rev_tables,
+                                                    mixed.labels)
+        out_valid = mixed.valid & rev_en
+        ingress = EventFrame(labels=jnp.where(out_valid, chip, 0),
+                             times=mixed.times, valid=out_valid)
         return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
-                                      unroutable=unroutable,
-                                      rerouted=rerouted)
-    mixed, dropped = make_frame_segmented(labels, None, valid, plan.capacity,
-                                          seg_lens, compact=plan.compact)
-    chip, rev_en = jax.vmap(routing.lookup_rev)(state.rev_tables, mixed.labels)
-    out_valid = mixed.valid & rev_en
-    ingress = EventFrame(labels=jnp.where(out_valid, chip, 0),
-                         times=mixed.times, valid=out_valid)
-    return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
-                                  unroutable=unroutable, rerouted=rerouted)
+                                      unroutable=unroutable, rerouted=rerouted)
 
 
 # ---------------------------------------------------------------------------
@@ -1251,18 +1268,20 @@ def fabric_exchange(frame: EventFrame, axis_names: tuple[str, ...],
     degraded = plan.degraded or health is not None
     cap_in = frame.labels.shape[-1]
 
-    wire, fwd_en = routing.lookup_fwd(fwd_table, frame.labels)
-    ev = frame.valid & fwd_en
-    times = (_egress_times(frame.times, ev, timing)
-             if timing is not None else None)
-    u0 = levels[0].link_capacity
-    if u0 is not None:
-        packed, uplink = make_frame(wire, times, ev, u0)
-        wire, ev = packed.labels, packed.valid
-        if timing is not None:
-            times = packed.times
-    else:
-        uplink = jnp.zeros((), jnp.int32)
+    # The leaf's forward lookup and its uplink pack.
+    with jax.named_scope("leaf"):
+        wire, fwd_en = routing.lookup_fwd(fwd_table, frame.labels)
+        ev = frame.valid & fwd_en
+        times = (_egress_times(frame.times, ev, timing)
+                 if timing is not None else None)
+        u0 = levels[0].link_capacity
+        if u0 is not None:
+            packed, uplink = make_frame(wire, times, ev, u0)
+            wire, ev = packed.labels, packed.valid
+            if timing is not None:
+                times = packed.times
+        else:
+            uplink = jnp.zeros((), jnp.int32)
 
     if degraded:
         # This shard's global leaf index, from the per-level coordinates.
@@ -1280,114 +1299,117 @@ def fabric_exchange(frame: EventFrame, axis_names: tuple[str, ...],
     gsize = 1
     parts_w, parts_en, parts_t, seg_lens = [], [], [], []
     for i, lvl in enumerate(levels):
-        f = lvl.fan_in
-        if degraded:
-            # Every leaf of a tier-i entity redundantly carries the entity
-            # stream, so per-leaf attribution mirrors the stacked executor:
-            # count this entity's (pre-mask) events against my own leaf.
-            ent_me = leaf // gsize
-            dyn_up = None if health is None else health.uplink[i]
-            flow_ok, live_detour = _flow_masks(lvl, dyn_up,
-                                               plan.n_nodes // gsize)
+        with jax.named_scope(f"level{i}"):
+            f = lvl.fan_in
+            if degraded:
+                # Every leaf of a tier-i entity redundantly carries the entity
+                # stream, so per-leaf attribution mirrors the stacked executor:
+                # count this entity's (pre-mask) events against my own leaf.
+                ent_me = leaf // gsize
+                dyn_up = None if health is None else health.uplink[i]
+                flow_ok, live_detour = _flow_masks(lvl, dyn_up,
+                                                   plan.n_nodes // gsize)
+                if flow_ok is not None:
+                    _, my_v = unpack_wire16(cur_words)
+                    my_count = my_v.sum().astype(jnp.int32)
+                    unroutable = unroutable + jnp.where(flow_ok[ent_me], 0,
+                                                        my_count)
+                    rerouted = rerouted + jnp.where(live_detour[ent_me],
+                                                    my_count, 0)
+                dyn_down = None if health is None else health.downlink[i]
+                d_ok = _down_mask(lvl, dyn_down, ent_me)
+                if d_ok is not None:
+                    recv_ok = d_ok if recv_ok is None else recv_ok & d_ok
+            else:
+                flow_ok = None
+            if routed:
+                perms = edge_neighbor_permutes(
+                    _concrete_enables(lvl.enables),
+                    prune=(i + 1 == len(levels)))
+                g_words = _routed_plane(cur_words, axis_names[i], f, perms)
+                g_times = (_routed_plane(cur_times, axis_names[i], f, perms)
+                           if timing is not None else None)
+            else:
+                g_words = jax.lax.all_gather(cur_words, axis_names[i], axis=0)
+                g_times = (jax.lax.all_gather(cur_times, axis_names[i], axis=0)
+                           if timing is not None else None)
+            me = jax.lax.axis_index(axis_names[i])
             if flow_ok is not None:
-                _, my_v = unpack_wire16(cur_words)
-                my_count = my_v.sum().astype(jnp.int32)
-                unroutable = unroutable + jnp.where(flow_ok[ent_me], 0,
-                                                    my_count)
-                rerouted = rerouted + jnp.where(live_detour[ent_me],
-                                                my_count, 0)
-            dyn_down = None if health is None else health.downlink[i]
-            d_ok = _down_mask(lvl, dyn_down, ent_me)
-            if d_ok is not None:
-                recv_ok = d_ok if recv_ok is None else recv_ok & d_ok
-        else:
-            flow_ok = None
-        if routed:
-            perms = edge_neighbor_permutes(
-                _concrete_enables(lvl.enables),
-                prune=(i + 1 == len(levels)))
-            g_words = _routed_plane(cur_words, axis_names[i], f, perms)
-            g_times = (_routed_plane(cur_times, axis_names[i], f, perms)
-                       if timing is not None else None)
-        else:
-            g_words = jax.lax.all_gather(cur_words, axis_names[i], axis=0)
-            g_times = (jax.lax.all_gather(cur_times, axis_names[i], axis=0)
-                       if timing is not None else None)
-        me = jax.lax.axis_index(axis_names[i])
-        if flow_ok is not None:
-            # Gathered slot s holds the entity (leaf // gnext) * f + s.
-            slots = (leaf // (gsize * f)) * f + jnp.arange(f)
-            flow_s = flow_ok[slots]
+                # Gathered slot s holds the entity (leaf // gnext) * f + s.
+                slots = (leaf // (gsize * f)) * f + jnp.arange(f)
+                flow_s = flow_ok[slots]
+                if timing is not None:
+                    _, g_v = unpack_wire16(g_words)
+                    pen = _detour_penalty(lvl, timing, g_v)
+                    g_times = jnp.where(live_detour[slots][:, None] & g_v,
+                                        g_times + pen, g_times)
+                    g_times = jnp.where(flow_s[:, None], g_times, 0)
+                g_words = jnp.where(flow_s[:, None], g_words, 0)
+            gate = lvl.enables[:, me]                       # [f]
+            if i > 0:
+                gate = gate & (jnp.arange(f) != me)
+            en = jnp.broadcast_to(gate[:, None], g_words.shape).reshape(-1)
+            if recv_ok is not None:
+                _, g_v = unpack_wire16(g_words.reshape(-1))
+                lost = (g_v & en).sum().astype(jnp.int32)
+                unroutable = unroutable + jnp.where(recv_ok, 0, lost)
+                en = en & recv_ok
+            parts_w.append(g_words.reshape(-1))
+            parts_en.append(en)
             if timing is not None:
-                _, g_v = unpack_wire16(g_words)
-                pen = _detour_penalty(lvl, timing, g_v)
-                g_times = jnp.where(live_detour[slots][:, None] & g_v,
-                                    g_times + pen, g_times)
-                g_times = jnp.where(flow_s[:, None], g_times, 0)
-            g_words = jnp.where(flow_s[:, None], g_words, 0)
-        gate = lvl.enables[:, me]                       # [f]
-        if i > 0:
-            gate = gate & (jnp.arange(f) != me)
-        en = jnp.broadcast_to(gate[:, None], g_words.shape).reshape(-1)
-        if recv_ok is not None:
-            _, g_v = unpack_wire16(g_words.reshape(-1))
-            lost = (g_v & en).sum().astype(jnp.int32)
-            unroutable = unroutable + jnp.where(recv_ok, 0, lost)
-            en = en & recv_ok
-        parts_w.append(g_words.reshape(-1))
-        parts_en.append(en)
-        if timing is not None:
-            parts_t.append(g_times.reshape(-1))
-        seg_lens += list(layout[i])
-        gsize = gsize * f
+                parts_t.append(g_times.reshape(-1))
+            seg_lens += list(layout[i])
+            gsize = gsize * f
 
-        if i + 1 < len(levels):
-            nxt = levels[i + 1]
-            s_words = g_words.reshape(-1)
-            s_labels, s_valid = unpack_wire16(s_words)
-            if timing is not None:
-                okp = s_valid.astype(jnp.int32)
-                prank = jnp.cumsum(okp) - okp
-                extra = (nxt.extra_ns if nxt.extra_ns is not None
-                         else timing.second_layer_extra_ns)
-                s_t = jnp.where(s_valid, g_times.reshape(-1) + extra
-                                + _queue_wait_i32(prank, timing.uplink_queue),
-                                0)
-            else:
-                s_t = None
-            if nxt.link_capacity is not None:
-                up, drop = make_frame(s_labels, s_t, s_valid,
-                                      nxt.link_capacity)
-                cur_words = pack_wire16(up.labels, up.valid)
-                cur_times = up.times if timing is not None else None
-                uplink = uplink + drop
-            else:
-                cur_words = s_words
-                cur_times = s_t
+            if i + 1 < len(levels):
+                nxt = levels[i + 1]
+                s_words = g_words.reshape(-1)
+                s_labels, s_valid = unpack_wire16(s_words)
+                if timing is not None:
+                    okp = s_valid.astype(jnp.int32)
+                    prank = jnp.cumsum(okp) - okp
+                    extra = (nxt.extra_ns if nxt.extra_ns is not None
+                             else timing.second_layer_extra_ns)
+                    wait = _queue_wait_i32(prank, timing.uplink_queue)
+                    s_t = jnp.where(s_valid,
+                                    g_times.reshape(-1) + extra + wait, 0)
+                else:
+                    s_t = None
+                if nxt.link_capacity is not None:
+                    up, drop = make_frame(s_labels, s_t, s_valid,
+                                          nxt.link_capacity)
+                    cur_words = pack_wire16(up.labels, up.valid)
+                    cur_times = up.times if timing is not None else None
+                    uplink = uplink + drop
+                else:
+                    cur_words = s_words
+                    cur_times = s_t
 
-    flat_words = jnp.concatenate(parts_w)
-    flat_en = jnp.concatenate(parts_en)
-    flat_times = (jnp.concatenate(parts_t) if timing is not None else None)
-    seg_lens = tuple(seg_lens)
-    if use_fused or timing is not None:
-        ingress, dropped = _fused_merge(flat_words, flat_en, rev_table,
-                                        plan.capacity, seg_lens=seg_lens,
-                                        compact=plan.compact, timing=timing,
-                                        use_fused=use_fused,
-                                        times=flat_times)
+    # The destination merge of every level's parts.
+    with jax.named_scope("merge"):
+        flat_words = jnp.concatenate(parts_w)
+        flat_en = jnp.concatenate(parts_en)
+        flat_times = (jnp.concatenate(parts_t) if timing is not None else None)
+        seg_lens = tuple(seg_lens)
+        if use_fused or timing is not None:
+            ingress, dropped = _fused_merge(
+                flat_words, flat_en, rev_table, plan.capacity,
+                seg_lens=seg_lens, compact=plan.compact, timing=timing,
+                use_fused=use_fused, times=flat_times)
+            return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
+                                          unroutable=unroutable,
+                                          rerouted=rerouted)
+        g_labels, g_valid = unpack_wire16(flat_words)
+        mixed, dropped = make_frame_segmented(g_labels, None,
+                                              g_valid & flat_en,
+                                              plan.capacity, seg_lens,
+                                              compact=plan.compact)
+        chip, rev_en = routing.lookup_rev(rev_table, mixed.labels)
+        out_valid = mixed.valid & rev_en
+        ingress = EventFrame(labels=jnp.where(out_valid, chip, 0),
+                             times=mixed.times, valid=out_valid)
         return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
-                                      unroutable=unroutable,
-                                      rerouted=rerouted)
-    g_labels, g_valid = unpack_wire16(flat_words)
-    mixed, dropped = make_frame_segmented(g_labels, None, g_valid & flat_en,
-                                          plan.capacity, seg_lens,
-                                          compact=plan.compact)
-    chip, rev_en = routing.lookup_rev(rev_table, mixed.labels)
-    out_valid = mixed.valid & rev_en
-    ingress = EventFrame(labels=jnp.where(out_valid, chip, 0),
-                         times=mixed.times, valid=out_valid)
-    return ingress, ExchangeDrops(congestion=dropped, uplink=uplink,
-                                  unroutable=unroutable, rerouted=rerouted)
+                                      unroutable=unroutable, rerouted=rerouted)
 
 
 # ---------------------------------------------------------------------------

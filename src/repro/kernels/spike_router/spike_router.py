@@ -293,6 +293,8 @@ def _pack_call(ok: jax.Array, payload: jax.Array, *, capacity: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        # One name for every caller, so the profiler names the kernel alike.
+        name="spike_router_pack",
     )(*operands)
     return (*(o[:b] for o in outs[:-1]), outs[-1][:b, 0])
 

@@ -36,8 +36,18 @@ independent batch-1 runs (the engine benchmark's hard parity gate).
 
 A FIFO request queue with admission-on-free-slot (continuous-batching
 style, after MaxText's prefill/insert/generate engine) sits on top; the CLI
-demo is ``launch/serve_emulation.py`` and the throughput recording is
-``benchmarks/engine_throughput.py`` (``stream_engine_*`` keys).
+demo is ``launch/serve_emulation.py`` and the throughput is measured by the
+benchmark cell ``ext4case_96chip.engine_plastic`` (``bench/run.py``).
+
+The host loop records its work as ``jax.profiler.TraceAnnotation`` spans,
+with counters as their arguments, which a profiler trace records on the
+clock of the device's ops: ``engine.step`` (``live`` slot-steps that advanced a
+session, ``slot_steps``, ``finished``), ``engine.upload`` (``bytes``),
+``engine.wait``, ``engine.readback`` (``bytes``, ``what``),
+``engine.account``, ``engine.finalize`` (``session``) and ``engine.admit``
+(``session``, ``queued_ms``).  The window program's own work outside
+``run_stream`` runs under the named scope ``slots``.  With no profiler
+running a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -176,27 +186,31 @@ class EmulationEngine:
             return pick
 
         def _step(params, state, plast, stim, cursor, mask, reset):
-            # Freshly admitted slots start from the init row; folding the
-            # reset in here (one select over the state) keeps admission
-            # O(state) per window instead of O(state) per admitted session.
-            init = netlib.init_state(cfg, slots)
-            state = netlib.NetworkState(
-                chips=jax.tree.map(_row_select(reset, 1), init.chips,
-                                   state.chips),
-                inflight=_row_select(reset, 2)(init.inflight,
-                                               state.inflight))
-            if plast is not None:
-                plast = jax.tree.map(
-                    _row_select(reset, 1),
-                    netlib.init_slot_plasticity(params, slots), plast)
-            # Per-slot window slice of the stimulus buffer at each slot's
-            # own cursor, gated by the (occupancy x remaining-length) mask.
-            win = jax.vmap(lambda s, c: jax.lax.dynamic_slice_in_dim(
-                s, c, window, 0))(stim, cursor)
-            win = jnp.where(mask.T[:, :, None, None], win, 0.0)
-            drives = jnp.zeros((window, cfg.n_chips, slots,
-                                cfg.chip.n_rows), jnp.float32)
-            drives = drives.at[:, stim_idx].set(win.transpose(1, 2, 0, 3))
+            with jax.named_scope("slots"):
+                # Freshly admitted slots start from the init row; folding
+                # the reset in here (one select over the state) keeps
+                # admission O(state) per window instead of O(state) per
+                # admitted session.
+                init = netlib.init_state(cfg, slots)
+                state = netlib.NetworkState(
+                    chips=jax.tree.map(_row_select(reset, 1), init.chips,
+                                       state.chips),
+                    inflight=_row_select(reset, 2)(init.inflight,
+                                                   state.inflight))
+                if plast is not None:
+                    plast = jax.tree.map(
+                        _row_select(reset, 1),
+                        netlib.init_slot_plasticity(params, slots), plast)
+                # Per-slot window slice of the stimulus buffer at each
+                # slot's own cursor, gated by the (occupancy x
+                # remaining-length) mask.
+                win = jax.vmap(lambda s, c: jax.lax.dynamic_slice_in_dim(
+                    s, c, window, 0))(stim, cursor)
+                win = jnp.where(mask.T[:, :, None, None], win, 0.0)
+                drives = jnp.zeros((window, cfg.n_chips, slots,
+                                    cfg.chip.n_rows), jnp.float32)
+                drives = drives.at[:, stim_idx].set(
+                    win.transpose(1, 2, 0, 3))
             out = stlib.run_stream(
                 params, state, drives, cfg, fabric=plan, timed=timed,
                 overlap=overlap, use_fused=use_fused,
@@ -206,13 +220,15 @@ class EmulationEngine:
                 payload = out._replace(state=self._row_like,  # not hauled
                                        plasticity=None)
             else:
-                payload = {
-                    "spike_count": out.spikes.sum(axis=(0, 1, 3)),
-                    "dropped": out.dropped.sum(axis=(0, 1)),
-                    "uplink_dropped": out.uplink_dropped.sum(axis=(0, 1)),
-                    "unroutable": out.unroutable.sum(axis=(0, 1)),
-                    "rerouted": out.rerouted.sum(axis=(0, 1)),
-                }
+                with jax.named_scope("slots"):
+                    payload = {
+                        "spike_count": out.spikes.sum(axis=(0, 1, 3)),
+                        "dropped": out.dropped.sum(axis=(0, 1)),
+                        "uplink_dropped": out.uplink_dropped.sum(
+                            axis=(0, 1)),
+                        "unroutable": out.unroutable.sum(axis=(0, 1)),
+                        "rerouted": out.rerouted.sum(axis=(0, 1)),
+                    }
                 if timed:
                     payload["latency_ns"] = out.latency_ns
                     payload["latency_valid"] = out.latency_valid
@@ -304,31 +320,34 @@ class EmulationEngine:
             if free is None:
                 return
             sid, stim, restore_from, t_sub = self._queue.popleft()
-            if restore_from is None:
-                # Fresh session: no device work now — the slot's reset to
-                # the init row rides inside the next step() call.
-                self._pending_reset[free] = True
-                start = 0
-            else:
-                ck = elastic.restore_stream_checkpoint(
-                    restore_from, self._row_like,
-                    plasticity_like=self._row_plast_like,
-                    expect_fingerprint=self._fingerprint)
-                self._state, self._plast = self._insert_fn(
-                    self._state, self._plast, jnp.int32(free), ck.state,
-                    ck.plasticity)
-                self._pending_reset[free] = False
-                start = ck.step
-            self._stim[free] = 0.0
-            self._stim[free, :stim.shape[0]] = stim
-            self._cursor[free] = start
-            self._length[free] = stim.shape[0]
-            # ``delivered`` counts steps emulated by *this* engine run — a
-            # restored session resumes at cursor=start but its result only
-            # carries the post-restore windows (stitch with the evicted
-            # partial result for the full raster).
-            self._sessions[free] = _Session(sid=sid, length=stim.shape[0],
-                                            submitted_at=t_sub)
+            with jax.profiler.TraceAnnotation(
+                    "engine.admit", session=sid,
+                    queued_ms=(time.time() - t_sub) * 1e3):
+                if restore_from is None:
+                    # Fresh session: no device work now — the slot's reset
+                    # to the init row rides inside the next step() call.
+                    self._pending_reset[free] = True
+                    start = 0
+                else:
+                    ck = elastic.restore_stream_checkpoint(
+                        restore_from, self._row_like,
+                        plasticity_like=self._row_plast_like,
+                        expect_fingerprint=self._fingerprint)
+                    self._state, self._plast = self._insert_fn(
+                        self._state, self._plast, jnp.int32(free), ck.state,
+                        ck.plasticity)
+                    self._pending_reset[free] = False
+                    start = ck.step
+                self._stim[free] = 0.0
+                self._stim[free, :stim.shape[0]] = stim
+                self._cursor[free] = start
+                self._length[free] = stim.shape[0]
+                # ``delivered`` counts steps emulated by *this* engine run
+                # — a restored session resumes at cursor=start but its
+                # result only carries the post-restore windows (stitch with
+                # the evicted partial result for the full raster).
+                self._sessions[free] = _Session(
+                    sid=sid, length=stim.shape[0], submitted_at=t_sub)
 
     # -- advance ------------------------------------------------------------
 
@@ -340,25 +359,34 @@ class EmulationEngine:
         if not occ.any():
             return 0
         remaining = np.where(occ, self._length - self._cursor, 0)
-        mask = (np.arange(self.window)[:, None] < remaining[None, :])
-        reset = self._pending_reset.copy()
-        self._state, self._plast, payload = self.window_fn(
-            self.params, self._state, self._plast, jnp.asarray(self._stim),
-            jnp.asarray(self._cursor), jnp.asarray(mask),
-            jnp.asarray(reset))
-        # Only the resets this call materialized — _admit below may flag
-        # new ones for the *next* window.
-        self._pending_reset &= ~reset
-        self._account(payload, remaining)
-        self._cursor = np.where(
-            occ, np.minimum(self._cursor + self.window, self._length),
-            self._cursor)
-        finished = 0
-        for slot in range(self.slots):
-            if occ[slot] and self._cursor[slot] >= self._length[slot]:
-                self._finalize(slot)
-                finished += 1
-        self._admit()
+        with jax.profiler.TraceAnnotation(
+                "engine.step",
+                live=int(np.minimum(remaining, self.window).sum()),
+                slot_steps=self.slots * self.window,
+                finished=int((occ & (remaining <= self.window)).sum())):
+            mask = (np.arange(self.window)[:, None] < remaining[None, :])
+            reset = self._pending_reset.copy()
+            host = (self._stim, self._cursor, mask, reset)
+            with jax.profiler.TraceAnnotation(
+                    "engine.upload", bytes=sum(a.nbytes for a in host)):
+                args = [jnp.asarray(a) for a in host]
+            self._state, self._plast, payload = self.window_fn(
+                self.params, self._state, self._plast, *args)
+            # Only the resets this call materialized — _admit below may flag
+            # new ones for the *next* window.
+            self._pending_reset &= ~reset
+            with jax.profiler.TraceAnnotation("engine.wait"):
+                jax.block_until_ready(payload)
+            self._account(payload, remaining)
+            self._cursor = np.where(
+                occ, np.minimum(self._cursor + self.window, self._length),
+                self._cursor)
+            finished = 0
+            for slot in range(self.slots):
+                if occ[slot] and self._cursor[slot] >= self._length[slot]:
+                    self._finalize(slot)
+                    finished += 1
+            self._admit()
         return finished
 
     def window_args(self) -> tuple:
@@ -376,45 +404,45 @@ class EmulationEngine:
         call before timing so the clock never includes jit compilation."""
         jax.block_until_ready(self.window_fn(*self.window_args())[0])
 
+    @staticmethod
+    def _readback(tree, what: str):
+        """Host copies of the device arrays of ``tree``, under one
+        ``engine.readback`` span."""
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(tree))
+        with jax.profiler.TraceAnnotation("engine.readback", bytes=nbytes,
+                                          what=what):
+            return jax.tree.map(np.asarray, tree)
+
     def _account(self, payload, remaining) -> None:
-        if self.keep_spikes:
-            spikes = np.asarray(payload.spikes)
-            drops = {k: np.asarray(getattr(payload, k))
-                     for k in ("dropped", "uplink_dropped", "unroutable",
-                               "rerouted")}
-            lat = lat_valid = None
-            if self.timed:
-                lat = np.asarray(payload.latency_ns)
-                lat_valid = np.asarray(payload.latency_valid)
+        drop_keys = ("dropped", "uplink_dropped", "unroutable", "rerouted")
+        with jax.profiler.TraceAnnotation("engine.account"):
+            if self.keep_spikes:
+                wanted = ("spikes", *drop_keys)
+                if self.timed:
+                    wanted += ("latency_ns", "latency_valid")
+                host = self._readback(
+                    {k: getattr(payload, k) for k in wanted}, "raster")
+            else:
+                host = self._readback(payload, "counts")
+            lat, lat_valid = host.get("latency_ns"), host.get("latency_valid")
             for slot, sess in enumerate(self._sessions):
                 if sess is None or remaining[slot] <= 0:
                     continue
                 w = int(min(self.window, remaining[slot]))
-                sess.spike_windows.append(spikes[:w, :, slot])
-                sess.spike_count += float(spikes[:w, :, slot].sum())
-                for k, v in drops.items():
-                    sess.drops[k] += int(v[:, :, slot].sum())
+                if self.keep_spikes:
+                    spikes = host["spikes"][:w, :, slot]
+                    sess.spike_windows.append(spikes)
+                    sess.spike_count += float(spikes.sum())
+                    for k in drop_keys:
+                        sess.drops[k] += int(host[k][:, :, slot].sum())
+                else:
+                    sess.spike_count += float(host["spike_count"][slot])
+                    for k in drop_keys:
+                        sess.drops[k] += int(host[k][slot])
                 if lat is not None:
                     sess.lat_samples.append(
                         lat[:, :, slot][lat_valid[:, :, slot]])
                 sess.delivered += w
-        else:
-            host = {k: np.asarray(v) for k, v in payload.items()
-                    if k not in ("latency_ns", "latency_valid")}
-            lat = lat_valid = None
-            if self.timed:
-                lat = np.asarray(payload["latency_ns"])
-                lat_valid = np.asarray(payload["latency_valid"])
-            for slot, sess in enumerate(self._sessions):
-                if sess is None or remaining[slot] <= 0:
-                    continue
-                sess.spike_count += float(host["spike_count"][slot])
-                for k in sess.drops:
-                    sess.drops[k] += int(host[k][slot])
-                if lat is not None:
-                    sess.lat_samples.append(
-                        lat[:, :, slot][lat_valid[:, :, slot]])
-                sess.delivered += int(min(self.window, remaining[slot]))
 
     # -- completion ---------------------------------------------------------
 
@@ -436,7 +464,8 @@ class EmulationEngine:
         else:
             _, row = self._extract_fn(self._state, self._plast,
                                       jnp.int32(slot))
-        return jax.tree.map(lambda a: np.asarray(a)[:, 0], row)
+        row = self._readback(row, "plasticity")
+        return jax.tree.map(lambda a: a[:, 0], row)
 
     def _result_of(self, slot: int, *, evicted_to=None) -> SessionResult:
         sess = self._sessions[slot]
@@ -454,9 +483,11 @@ class EmulationEngine:
             evicted_to=evicted_to, **sess.drops)
 
     def _finalize(self, slot: int) -> None:
-        result = self._result_of(slot)
-        self._results[result.session_id] = result
-        self._sessions[slot] = None
+        with jax.profiler.TraceAnnotation(
+                "engine.finalize", session=self._sessions[slot].sid):
+            result = self._result_of(slot)
+            self._results[result.session_id] = result
+            self._sessions[slot] = None
 
     def collect(self, session_id: int) -> SessionResult:
         """Pop a finished session's result (KeyError while still running)."""

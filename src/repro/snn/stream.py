@@ -33,6 +33,11 @@ Modes and topologies mirror ``repro.snn.network``:
 * ``mode="dense"``  — the differentiable surrogate (routing matrices), so
   BPTT through ``run_stream`` is the training hot loop.
 
+Each stage runs under a ``jax.named_scope`` — ``chip``, ``plasticity``,
+``egress``, ``fabric`` (with ``leaf``, ``level<i>`` and ``merge`` inside) and
+``ingress`` (row decode and delay line) — which the compiled program keeps
+as each op's name, so a profiler trace splits the device time by stage.
+
 The sharded twin (exchange scan under one ``shard_map``) is
 ``repro.core.aggregator.StarInterconnect.stream_fn``; the multi-step Pallas
 kernel behind the fused exchange is ``repro.kernels.spike_router``.
@@ -338,26 +343,32 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         """Egress tap → exchange → ingress decode, vmapped over batch."""
 
         def one_batch(spk_b):  # [n_chips, n_neurons]
-            # Timed egress: all spikes of the window depart at its open
-            # (time 0 on the int32 lane), so the ingress times *are* the
-            # chip-to-chip wire latencies.
-            times = jnp.zeros_like(labels_grid) if timed else None
-            frames, egress_drop = make_frame(labels_grid, times, spk_b > 0.5,
-                                             cfg.capacity)
-            ingress, drops = fablib.fabric_route_step(
-                params.router, frames, plan_seg, use_fused=use_fused,
-                timing=timing, health=health_t)
-            drives = jax.vmap(
-                lambda lab, val, rmap: chiplib.labels_to_rows(
-                    lab[None], val[None], rmap, cfg.chip.n_rows)[0])(
-                        ingress.labels, ingress.valid, params.row_of_label)
-            if timed:
-                lat, lat_valid = ingress.times, ingress.valid
-            else:
-                lat = jnp.zeros((*ingress.valid.shape[:-1], 0), jnp.int32)
-                lat_valid = jnp.zeros(lat.shape, jnp.bool_)
-            return (drives, egress_drop + drops.congestion, drops.uplink,
-                    lat, lat_valid, drops.unroutable, drops.rerouted)
+            with jax.named_scope("egress"):
+                # Timed egress: all spikes of the window depart at its open
+                # (time 0 on the int32 lane), so the ingress times *are* the
+                # chip-to-chip wire latencies.
+                times = jnp.zeros_like(labels_grid) if timed else None
+                frames, egress_drop = make_frame(labels_grid, times,
+                                                 spk_b > 0.5, cfg.capacity)
+            with jax.named_scope("fabric"):
+                ingress, drops = fablib.fabric_route_step(
+                    params.router, frames, plan_seg, use_fused=use_fused,
+                    timing=timing, health=health_t)
+            with jax.named_scope("ingress"):
+                drives = jax.vmap(
+                    lambda lab, val, rmap: chiplib.labels_to_rows(
+                        lab[None], val[None], rmap, cfg.chip.n_rows)[0])(
+                            ingress.labels, ingress.valid,
+                            params.row_of_label)
+                if timed:
+                    lat, lat_valid = ingress.times, ingress.valid
+                else:
+                    lat = jnp.zeros((*ingress.valid.shape[:-1], 0),
+                                    jnp.int32)
+                    lat_valid = jnp.zeros(lat.shape, jnp.bool_)
+                return (drives, egress_drop + drops.congestion,
+                        drops.uplink, lat, lat_valid, drops.unroutable,
+                        drops.rerouted)
 
         return jax.vmap(one_batch, in_axes=1,
                         out_axes=(1, 1, 1, 1, 1, 1, 1))(spikes)
@@ -368,29 +379,31 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         masked slots' spikes *before* recording/egress/plasticity, so an
         idle slot emits no events and (under per-slot plasticity) freezes
         its traces and weights."""
-        if per_slot:
-            new_chips, spikes = jax.vmap(
-                lambda p, s, d, w: chiplib.chip_step_slots(p, s, d, w,
-                                                           cfg.chip))(
-                    params.chips, chips, drive, plast.weights)
-        else:
-            # Plastic runs integrate the *evolving* weights from the carry;
-            # non-plastic runs keep the static params (same program as
-            # before — ``plast`` is an empty pytree then).
-            chip_params = (params.chips if plast is None
-                           else params.chips._replace(weights=plast.weights))
-            new_chips, spikes = jax.vmap(
-                lambda p, s, d: chiplib.chip_step(p, s, d, cfg.chip))(
-                    chip_params, chips, drive)
-        if mask_t is not None:
-            spikes = jnp.where(mask_t[None, :, None], spikes, 0.0)
-        if plast is not None:
+        with jax.named_scope("chip"):
             if per_slot:
-                plast = plaslib.stdp_slot_step(plast, drive, spikes,
-                                               plasticity, mask=mask_t)
+                new_chips, spikes = jax.vmap(
+                    lambda p, s, d, w: chiplib.chip_step_slots(p, s, d, w,
+                                                               cfg.chip))(
+                        params.chips, chips, drive, plast.weights)
             else:
-                plast = plaslib.stdp_stream_step(plast, drive, spikes,
-                                                 plasticity)
+                # Plastic runs integrate the *evolving* weights from the
+                # carry; non-plastic runs keep the static params (same
+                # program as before — ``plast`` is an empty pytree then).
+                chip_params = (params.chips if plast is None else
+                               params.chips._replace(weights=plast.weights))
+                new_chips, spikes = jax.vmap(
+                    lambda p, s, d: chiplib.chip_step(p, s, d, cfg.chip))(
+                        chip_params, chips, drive)
+            if mask_t is not None:
+                spikes = jnp.where(mask_t[None, :, None], spikes, 0.0)
+        if plast is not None:
+            with jax.named_scope("plasticity"):
+                if per_slot:
+                    plast = plaslib.stdp_slot_step(plast, drive, spikes,
+                                                   plasticity, mask=mask_t)
+                else:
+                    plast = plaslib.stdp_stream_step(plast, drive, spikes,
+                                                     plasticity)
         return new_chips, spikes, plast
 
     def make_body(plan_seg):
@@ -401,11 +414,12 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         def body(carry, xs):
             drive_t, health_t, mask_t = xs
             chips, inflight, t, plast = carry
-            slot = jax.lax.rem(t, delay)
-            # Ingress: consume the delay-line slot written ``delay`` steps
-            # ago.
-            drive = drive_t + jax.lax.dynamic_index_in_dim(inflight, slot, 0,
-                                                           keepdims=False)
+            with jax.named_scope("ingress"):
+                slot = jax.lax.rem(t, delay)
+                # Ingress: consume the delay-line slot written ``delay``
+                # steps ago.
+                drive = drive_t + jax.lax.dynamic_index_in_dim(
+                    inflight, slot, 0, keepdims=False)
             new_chips, spikes, plast = chip_phase(chips, drive, plast, mask_t)
             if mode == "dense":
                 routed = jnp.einsum("sbn,sdnr->dbr", spikes, route_mats)
@@ -419,8 +433,9 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
             # Egress: the consumed slot is exactly the one due ``delay``
             # steps out — overwrite it in place (double buffering, no shift
             # copy).
-            inflight = jax.lax.dynamic_update_index_in_dim(inflight, routed,
-                                                           slot, 0)
+            with jax.named_scope("ingress"):
+                inflight = jax.lax.dynamic_update_index_in_dim(
+                    inflight, routed, slot, 0)
             return ((new_chips, inflight, t + 1, plast),
                     (spikes, dropped, uplink, lat, lat_valid, unroutable,
                      rerouted))
@@ -436,9 +451,10 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         def body(carry, xs):
             drive_t, _, mask_t = xs
             chips, inflight, t, plast, prev_spikes = carry
-            slot = jax.lax.rem(t, delay)
-            drive = drive_t + jax.lax.dynamic_index_in_dim(inflight, slot, 0,
-                                                           keepdims=False)
+            with jax.named_scope("ingress"):
+                slot = jax.lax.rem(t, delay)
+                drive = drive_t + jax.lax.dynamic_index_in_dim(
+                    inflight, slot, 0, keepdims=False)
             new_chips, spikes, plast = chip_phase(chips, drive, plast, mask_t)
             # prev_spikes were masked at production, so the deferred
             # exchange of a masked slot's window is already empty.
@@ -449,10 +465,11 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
             # The t == 0 dummy exchange (zero previous window) must not
             # clobber the caller's initial in-flight frame due at step
             # delay-1, hence the gate.
-            prev_slot = jax.lax.rem(t + delay - 1, delay)
-            written = jax.lax.dynamic_update_index_in_dim(inflight, routed,
-                                                          prev_slot, 0)
-            inflight = jnp.where(t > 0, written, inflight)
+            with jax.named_scope("ingress"):
+                prev_slot = jax.lax.rem(t + delay - 1, delay)
+                written = jax.lax.dynamic_update_index_in_dim(
+                    inflight, routed, prev_slot, 0)
+                inflight = jnp.where(t > 0, written, inflight)
             return ((new_chips, inflight, t + 1, plast, spikes),
                     (spikes, dropped, uplink, lat, lat_valid, unroutable,
                      rerouted))
@@ -483,9 +500,10 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
 
     plast0 = None
     if plasticity is not None:
-        plast0 = (plasticity_state if plasticity_state is not None
-                  else plaslib.init_stream_stdp(params.chips.weights,
-                                                ext_drives.shape[2]))
+        with jax.named_scope("plasticity"):
+            plast0 = (plasticity_state if plasticity_state is not None
+                      else plaslib.init_stream_stdp(params.chips.weights,
+                                                    ext_drives.shape[2]))
     carry = (state.chips, state.inflight, jnp.int32(0), plast0)
     if overlap:
         carry = (*carry, jnp.zeros((cfg.n_chips, ext_drives.shape[2],
@@ -511,8 +529,9 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         # zero dummy window).
         (routed, e_drop, e_up, e_lat, e_latv, e_unr, e_rer) = event_route(
             last_spikes, segments[-1][2], None)
-        inflight = jax.lax.dynamic_update_index_in_dim(
-            inflight, routed, (n_steps - 1) % delay, 0)
+        with jax.named_scope("ingress"):
+            inflight = jax.lax.dynamic_update_index_in_dim(
+                inflight, routed, (n_steps - 1) % delay, 0)
 
         def _shift(a, tail):
             return jnp.concatenate([a[1:], tail[None]], axis=0)
@@ -526,7 +545,8 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
     # Restore shift-register order so the final state is bit-exact with the
     # per-step path (slot ``t % delay`` was written last).
     if delay > 1 and n_steps % delay:
-        inflight = jnp.roll(inflight, -(n_steps % delay), axis=0)
+        with jax.named_scope("ingress"):
+            inflight = jnp.roll(inflight, -(n_steps % delay), axis=0)
     return StreamOut(state=netlib.NetworkState(chips=chips,
                                                inflight=inflight),
                      spikes=spikes, dropped=dropped, uplink_dropped=uplink,
