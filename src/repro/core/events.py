@@ -9,13 +9,15 @@ fixed-capacity ``EventFrame``s: a dense buffer of labels/timestamps plus a
 validity mask.  Capacity overflow drops events and counts them — the same
 semantics as the paper's lossy layer-1 path under continued congestion.
 
-Compaction scheme (fused exchange datapath): frames are packed with an
-exclusive prefix sum over the validity mask plus a masked scatter — the
-hardware's pack unit — rather than a stable sort.  Arrival order and drop
-counts are identical to the retired argsort scheme; the only observable
-difference is that invalid slots are now zero-filled instead of carrying
-sorted garbage.  The Pallas twin of this path lives in
-``repro.kernels.spike_router``.
+Compaction scheme: ``make_frame`` ranks the valid events with a prefix sum
+over the validity mask — the hardware's pack unit — rather than a stable
+sort, and fills each output slot with the event of its rank.  Arrival order
+and drop counts are identical to the retired argsort scheme; the only
+observable difference is that invalid slots are zero-filled instead of
+carrying sorted garbage.  This jnp form is the path off the TPU, and the
+fabric's uplink packs take it everywhere.  On the TPU the chips' egress
+(``repro.snn.stream``) packs through the Pallas pack kernel instead
+(``repro.kernels.spike_router.ops.pack_frame``), with the same result.
 """
 
 from __future__ import annotations
@@ -92,13 +94,15 @@ def make_frame(labels, times, valid, capacity: int) -> tuple[EventFrame, jax.Arr
     This is the hardware pack unit: an inclusive prefix sum over the validity
     mask ranks each valid event (arrival order preserved), and every output
     slot j gathers the event with rank j+1 via a vectorized binary search on
-    the monotone prefix sums — the gather-form inverse of the cumsum/scatter
-    compaction (the Pallas kernels in ``repro.kernels.spike_router`` use the
-    literal scatter).  O(C log N) gathers instead of the O(N log N) stable
-    sort plus three payload permutations the seed used
-    (see ``make_frame_argsort``).  Events ranked beyond ``capacity`` are
-    dropped and counted (layer-1 congestion semantics).  Invalid output
-    slots are zero-filled — labels and times of padding are always 0.
+    the monotone prefix sums.  O(C log N) gathers instead of the O(N log N)
+    stable sort plus three payload permutations of ``make_frame_argsort``.
+    On the TPU these per-element gathers are slow; the stream's egress packs
+    there through ``repro.kernels.spike_router.ops.pack_frame``, whose
+    kernel compares each event's rank with every output slot and sums the
+    one writer's value in VMEM, and whose ``"jax"`` path is this function.
+    Events ranked beyond ``capacity`` are dropped and counted (layer-1
+    congestion semantics).  Invalid output slots are zero-filled — labels
+    and times of padding are always 0.
 
     ``times=None`` skips the timestamp gather and emits zeros (the exchange
     paths discard timestamps at egress, §III).

@@ -141,11 +141,12 @@ def _arrival_times(out_times: jax.Array, out_valid: jax.Array,
     return jnp.where(out_valid, out_times + timing.recv_fixed_ns, 0)
 
 
-def _kernel_mode(use_fused: bool) -> str:
-    """Kernel mode for the fused merges, resolved *eagerly* (never ``None``)
-    so the ops-level jit caches one entry per concrete mode — the chip
-    compile tests and parity tests monkeypatch ``repro.kernels.default_mode``
-    and must not hit a stale ``mode=None`` trace."""
+def kernel_mode(use_fused: bool) -> str:
+    """Kernel mode for the fused merges and the stream's egress pack,
+    resolved *eagerly* (never ``None``) so the ops-level jit caches one
+    entry per concrete mode — the chip compile tests and parity tests
+    monkeypatch ``repro.kernels.default_mode`` and must not hit a stale
+    ``mode=None`` trace."""
     from repro.kernels import default_mode
 
     return default_mode() if use_fused else "jax"
@@ -163,7 +164,7 @@ def _fused_merge(labels, valid, rev, capacity: int, *, seg_lens, compact,
         labels, valid, rev, capacity=capacity, seg_lens=seg_lens,
         compact=compact, times=times,
         queue=None if timing is None else timing.queue,
-        mode=_kernel_mode(use_fused))
+        mode=kernel_mode(use_fused))
     if timing is not None:
         out_l, out_v, out_t, dropped = outs
         out_t = _arrival_times(out_t, out_v, timing)
@@ -1010,7 +1011,7 @@ def fabric_route_step(state, frames: EventFrame, plan: FabricPlan, *,
         out_l, out_v, dropped = fused_exchange(
             frames.labels, frames.valid, state.fwd_tables, state.rev_tables,
             levels[0].enables, capacity=plan.capacity,
-            mode=_kernel_mode(True))
+            mode=kernel_mode(True))
         ingress = EventFrame(labels=out_l, times=jnp.zeros_like(out_l),
                              valid=out_v)
         zeros = jnp.zeros_like(dropped)

@@ -1,5 +1,9 @@
 """Public jit'd wrappers for the fused exchange datapath.
 
+``pack_frame``             the bare capacity pack, no LUT: the kernel twin
+                           of ``events.make_frame`` with the same return.
+                           The chips' egress in ``repro.snn.stream`` packs
+                           through it.
 ``route_and_pack``         egress only: fwd LUT + enable mask + capacity
                            pack (``interpret=True`` off the TPU).
 ``fused_exchange``         the full round (fwd LUT → route enables → merge →
@@ -25,12 +29,46 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.events import EventFrame, make_frame
 from repro.kernels import MODE_INTERPRET, MODE_JAX, MODE_PALLAS, default_mode
 from repro.kernels.spike_router import ref as _ref
-from repro.kernels.spike_router.spike_router import (exchange_fwd,
+from repro.kernels.spike_router.spike_router import (_pack_call,
+                                                     exchange_fwd,
                                                      exchange_stream_fwd,
                                                      merge_pack_fwd,
                                                      spike_router_fwd)
+
+
+@functools.partial(jax.jit, static_argnames=("capacity", "mode"))
+def pack_frame(labels: jax.Array, valid: jax.Array, *, capacity: int,
+               mode: str | None = None) -> tuple[EventFrame, jax.Array]:
+    """Compact events to the front of a capacity-bounded frame.
+
+    labels, valid: [..., n_events].  The same result as
+    ``events.make_frame(labels, None, valid, capacity)``, which is the
+    ``"jax"`` path: arrival order kept, events ranked beyond ``capacity``
+    dropped and counted, invalid slots zero-filled, ``times`` all zeros.
+    The kernel ranks by a one-hot compare and select-and-sum in VMEM, with
+    no per-element gather.
+
+    Returns (frame, dropped i32[...]).
+    """
+    if mode is None:
+        mode = default_mode()
+    if mode == MODE_JAX:
+        return make_frame(labels, None, valid, capacity)
+    if mode not in (MODE_PALLAS, MODE_INTERPRET):
+        raise ValueError(f"unknown exchange mode: {mode!r}")
+    lead = labels.shape[:-1]
+    n = labels.shape[-1]
+    out_l, out_v, dropped = _pack_call(
+        valid.reshape(-1, n).astype(jnp.int32),
+        labels.reshape(-1, n).astype(jnp.int32), capacity=capacity,
+        interpret=mode == MODE_INTERPRET)
+    out_l = out_l.reshape(*lead, capacity)
+    frame = EventFrame(labels=out_l, times=jnp.zeros_like(out_l),
+                       valid=out_v.reshape(*lead, capacity).astype(jnp.bool_))
+    return frame, dropped.reshape(lead)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))

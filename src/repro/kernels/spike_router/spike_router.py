@@ -28,6 +28,9 @@ a 2^15/2^16-entry table).  Four wrappers drive the one kernel:
                           the destination's rank-dependent queueing.  Used
                           by every merge of ``repro.core.fabric``.
 
+The bare pack, with no LUT, is ``_pack_call`` itself; ``ops.pack_frame``
+runs it for the chips' egress frames.
+
 Kernel layout: each grid cell packs ``ROWS`` = 8 streams (one sublane tile),
 the batch padded to a multiple of 8; the event axis is padded to a multiple
 of ``TILE`` = 128 with invalid slots (which never change a rank).  In VMEM
@@ -282,6 +285,16 @@ def _pack_call(ok: jax.Array, payload: jax.Array, *, capacity: int,
         in_specs.append(row_spec)
     kernel = functools.partial(_pack_kernel, capacity=capacity,
                                wire16=wire16, queue=queue)
+    # The kernel's work, for XLA's scheduler: without it the call looks free,
+    # and no prefetch is overlapped with it (the fabric's 25 MB forward LUT
+    # then stays in HBM behind the egress pack).  Per event: the rank matmul
+    # over its tile, and per output slot one compare plus a select and an
+    # add for each output lane.
+    cost = pl.CostEstimate(
+        flops=rows * width * (2 * TILE + capacity * (1 + 2 * n_lanes)),
+        transcendentals=0,
+        bytes_accessed=4 * (sum(o.size for o in operands)
+                            + rows * (n_lanes * capacity + 1)))
     outs = pl.pallas_call(
         kernel,
         grid=(rows // ROWS, width // TILE),
@@ -293,6 +306,7 @@ def _pack_call(ok: jax.Array, payload: jax.Array, *, capacity: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        cost_estimate=cost,
         # One name for every caller, so the profiler names the kernel alike.
         name="spike_router_pack",
     )(*operands)

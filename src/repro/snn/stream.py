@@ -40,7 +40,10 @@ as each op's name, so a profiler trace splits the device time by stage.
 
 The sharded twin (exchange scan under one ``shard_map``) is
 ``repro.core.aggregator.StarInterconnect.stream_fn``; the multi-step Pallas
-kernel behind the fused exchange is ``repro.kernels.spike_router``.
+kernel behind the fused exchange is ``repro.kernels.spike_router``.  Its
+pack kernel also packs the chips' egress frames on the TPU
+(``spike_router.ops.pack_frame``; ``events.make_frame`` off the TPU or with
+``use_fused=False``).
 """
 
 from __future__ import annotations
@@ -52,7 +55,7 @@ import jax.numpy as jnp
 
 from repro.core import fabric as fablib
 from repro.core import latency as latlib
-from repro.core.events import make_frame
+from repro.kernels.spike_router.ops import pack_frame
 from repro.snn import chip as chiplib
 from repro.snn import network as netlib
 from repro.snn import plasticity as plaslib
@@ -167,7 +170,8 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
         ``inter_enables``, event mode only — the dense surrogate encodes
         topology in ``route_mats``).  Both compile to 1-/2-level fabric
         plans internally; deeper topologies pass a plan via ``fabric``.
-      use_fused: event mode only; forwarded to the exchange kernels.
+      use_fused: event mode only; forwarded to the exchange kernels, and
+        governs the egress pack kernel alike.
       link_capacity / pod_capacity: hierarchical event mode only — the
         compact-before-gather uplink stages of
         ``route_step_hierarchical``; overflow lands in
@@ -339,17 +343,26 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
                 inter_enables=inter_enables, link_capacity=link_capacity,
                 pod_capacity=pod_capacity))
 
-    def event_route(spikes, plan_seg, health_t):
-        """Egress tap → exchange → ingress decode, vmapped over batch."""
+    # The egress pack runs where the fabric's merges run: the Pallas pack
+    # kernel on the TPU unless ``use_fused`` is off, ``make_frame`` elsewhere.
+    egress_mode = fablib.kernel_mode(fablib.fused_exchange_enabled()
+                                      if use_fused is None else use_fused)
 
-        def one_batch(spk_b):  # [n_chips, n_neurons]
-            with jax.named_scope("egress"):
-                # Timed egress: all spikes of the window depart at its open
-                # (time 0 on the int32 lane), so the ingress times *are* the
-                # chip-to-chip wire latencies.
-                times = jnp.zeros_like(labels_grid) if timed else None
-                frames, egress_drop = make_frame(labels_grid, times,
-                                                 spk_b > 0.5, cfg.capacity)
+    def event_route(spikes, plan_seg, health_t):
+        """Egress tap over every (batch, chip) row at once → exchange →
+        ingress decode, vmapped over batch."""
+        with jax.named_scope("egress"):
+            # Timed egress: all spikes of the window depart at its open
+            # (time 0 on the int32 lane), so the ingress times *are* the
+            # chip-to-chip wire latencies: the frame's zero times are the
+            # departures, and no time lane rides the pack.  Rows are
+            # batch-major, so the fabric's vmap below maps the leading axis.
+            fired = jnp.swapaxes(spikes, 0, 1) > 0.5  # [batch, n_chips, n]
+            all_frames, all_egress_drop = pack_frame(
+                jnp.broadcast_to(labels_grid, fired.shape), fired,
+                capacity=cfg.capacity, mode=egress_mode)
+
+        def one_batch(frames, egress_drop):  # [n_chips, capacity], [n_chips]
             with jax.named_scope("fabric"):
                 ingress, drops = fablib.fabric_route_step(
                     params.router, frames, plan_seg, use_fused=use_fused,
@@ -370,8 +383,8 @@ def run_stream(params: netlib.NetworkParams, state: netlib.NetworkState,
                         drops.uplink, lat, lat_valid, drops.unroutable,
                         drops.rerouted)
 
-        return jax.vmap(one_batch, in_axes=1,
-                        out_axes=(1, 1, 1, 1, 1, 1, 1))(spikes)
+        return jax.vmap(one_batch, out_axes=(1, 1, 1, 1, 1, 1, 1))(
+            all_frames, all_egress_drop)
 
     def chip_phase(chips, drive, plast, mask_t):
         """Chip step (shared or per-slot weights) + slot masking + the

@@ -132,6 +132,19 @@ def test_exchange_kernels_compile(one_chip, scenario):
     assert all("tpu_custom_call" in t for t in texts)
 
 
+@pytest.mark.parametrize("rows, capacity", [(480, 128), (1536, 96)])
+def test_egress_pack_compiles(one_chip, rows, capacity):
+    """The egress pack at the benchmark cells' shapes: batch x chips rows
+    of the published chip's 512 neurons, packed to the ingress capacity."""
+    from repro.kernels.spike_router.ops import pack_frame
+
+    fn = functools.partial(pack_frame, capacity=capacity, mode="pallas")
+    text = _compile(fn, one_chip,
+                    jnp.zeros((rows, chiplib.N_NEURONS), jnp.int32),
+                    jnp.zeros((rows, chiplib.N_NEURONS), jnp.bool_))
+    assert "tpu_custom_call" in text
+
+
 def test_engine_window_program_compiles(one_chip, pallas_mode):
     """The engine's window program at the published 256x512 chip on the
     96-chip extension fabric: timed, per-slot STDP, 8 slots — the program
