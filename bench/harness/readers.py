@@ -89,3 +89,18 @@ def kernel_share(ctx: dict, pattern: str):
         return None
     k = s.kernels_matching(pattern)
     return 100.0 * k / s.busy_ns if k else None
+
+
+def kernel_roofline(ctx: dict, pattern: str, work_bytes: float, ops: float):
+    """Share (%) of the device time of the Pallas kernels whose op name
+    matches ``pattern`` that ``work_bytes`` of HBM traffic and ``ops`` int8
+    operations, the kernels' work over the window, need at the chip's
+    peaks; None where no such kernel ran."""
+    s = ctx.get("summary")
+    ns = s.kernels_matching(pattern) if s is not None else 0.0
+    if not ns:
+        return None
+    share, bound = worklib.roofline(work_bytes, ops, ns * 1e-9, ctx["peak"])
+    ctx.setdefault("notes", []).append(
+        f"{pattern} kernel roofline bound by {bound}")
+    return share

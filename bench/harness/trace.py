@@ -41,6 +41,10 @@ class Summary:
     module_count: dict
     module_events: list                # (name, start, end) on chip 0
     spans: list                        # (name, start, end) bench host spans
+    # Filled by ``layers.attach``: {module: {scope path: own device ns}},
+    # and the program's host spans [(name, start, end, stats)].
+    scope_ns: dict | None = None
+    program_spans: list | None = None
 
     @property
     def window_ns(self) -> float:

@@ -1,0 +1,9 @@
+"""Own device time of the ops of the engine's window program under the scope
+``chip`` (the chip step: synaptic input, LIF update, spike decision) per
+emulated step, ms."""
+
+from bench.harness import layers
+
+
+def read(ctx):
+    return layers.scope_ms_per_step(ctx, "chip")

@@ -109,6 +109,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     import jax
 
     from bench.harness import check as checklib
+    from bench.harness import layers
     from bench.harness import peaks as peakslib
     from bench.harness import spec as speclib
     from bench.harness import trace as tracelib
@@ -138,10 +139,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     breakdown = None
     if trace:
         try:
-            summary = tracelib.load(tracelib.find_xplane(logdir))
+            xplane = tracelib.find_xplane(logdir)
+            summary = tracelib.load(xplane)
+            reduce_s = layers.attach(summary, xplane)
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
-        ctx.update(summary=summary, peak=peakslib.peaks(dev.device_kind))
+        ctx.update(summary=summary, peak=peakslib.peaks(dev.device_kind),
+                   notes=[f"scope and span reduction: {reduce_s:.3f} s"])
+        layers.note_idle_by_span(ctx)
         device["busy_s"] = summary.busy_ns * 1e-9
         device["window_s"] = summary.window_ns * 1e-9
         breakdown = tracelib.breakdown(summary)

@@ -30,12 +30,10 @@ from bench.harness import reference as ref  # noqa: E402
 from bench.harness import spec  # noqa: E402
 
 DATA = pathlib.Path(__file__).with_name("data")
-CELLS = {
-    "ext4case_96chip.engine_plastic": ("tiny_ext4case_96chip",
-                                       "tiny_engine_plastic"),
-    "projected_120chip.stream_timed": ("tiny_projected_120chip",
-                                       "tiny_stream_timed"),
-}
+# Each cell's small copy is found by name: ``data/tiny_<config>.json`` and
+# ``data/tiny_<traffic>.json``.
+CELLS = {w["name"]: (f"tiny_{w['config']}", f"tiny_{w['traffic']}")
+         for w in spec.load_benchmark(ROOT)["workloads"]}
 SEED = 2**31 + 4242
 
 
@@ -189,3 +187,30 @@ def test_fault_fails_without_kept_spikes(fault):
 def test_a_number_without_a_limit_is_an_error():
     with pytest.raises(KeyError, match="count_gap"):
         run(ENGINE, keep_spikes=False)
+
+
+def test_traced_run_reports_the_cells_layers(monkeypatch, tmp_path):
+    """A traced run reduces the program's scopes and spans after the window
+    and reports every per-layer metric of the cell.  The CPU's trace has no
+    TPU plane, so the recorded chip trace of the stream program stands in
+    for it."""
+    import gzip
+    import shutil
+
+    from bench.harness import peaks as peakslib
+    from bench.harness import trace as tracelib
+
+    recorded = tmp_path / "scoped.xplane.pb"
+    with gzip.open(DATA / "small_trace_scoped.xplane.pb.gz", "rb") as src, \
+            open(recorded, "wb") as dst:
+        shutil.copyfileobj(src, dst)
+    v5e = peakslib.peaks("TPU v5 lite")
+    monkeypatch.setattr(tracelib, "find_xplane", lambda _: str(recorded))
+    monkeypatch.setattr(peakslib, "peaks", lambda _: v5e)
+    cell = tiny_cell("projected_120chip.stream_timed")
+    res = runmod.run_cell(cell, SEED, 0.3, True, jax.devices(),
+                          t_start=time.perf_counter())
+    assert res["correct"], res["readings"]
+    assert set(res["metrics"]) == {m["name"] for m in cell.per_layer}
+    assert res["metrics"]["stream.fabric_leaf_ms_per_step"]["value"] > 0
+    assert res["device"]["busy_s"] > 0

@@ -65,6 +65,20 @@ def test_workload_resolves_its_files_by_name(workload):
         assert hasattr(runner, attr)
 
 
+@pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
+def test_workload_has_its_tiny_copy(workload):
+    """The CPU tests of ``correct`` find a cell's small copy by name:
+    ``tests/data/tiny_<config>.json`` and ``tests/data/tiny_<traffic>.json``,
+    of the same driving path and keys as the cell's own files."""
+    w = next(x for x in BENCH["workloads"] if x["name"] == workload)
+    data = ROOT / "bench" / "tests" / "data"
+    cfg = json.loads((data / f"tiny_{w['config']}.json").read_text())
+    mix = json.loads((data / f"tiny_{w['traffic']}.json").read_text())
+    cell = spec.resolve(BENCH, workload)
+    assert mix["path"] == cell.traffic["path"]
+    assert set(cfg) == set(cell.cfg)
+
+
 @pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_config_file_lists_every_reduction(config):
     entry = next(c for c in BENCH["configs"] if c["name"] == config)

@@ -133,33 +133,34 @@ def test_read_by_takes_the_nearest_scoped_reader():
 def traced(request, tmp_path_factory):
     path = _unzip(request.param, tmp_path_factory.mktemp("trace"))
     summary = tracelib.load(path)
-    scope_ns, level_ns = layers.scope_times(xspace.read(path),
-                                            summary.window)
-    return request.param, summary, scope_ns, level_ns
+    layers.attach(summary, path)
+    return request.param, summary
 
 
 def test_scopes_add_up_to_the_programs_own_op_times(traced):
     """Every op of the window lies in the one ``jit_stream_call`` module,
-    so the scopes and ``unscoped`` add up to the own op times that
+    so the scope paths and ``unscoped`` add up to the own op times that
     ``trace.load`` reduces independently."""
-    path, summary, scope_ns, _ = traced
-    assert list(scope_ns) == [m for m in summary.module_ns
-                              if "jit_stream_call" in m]
-    total = sum(v for parts in scope_ns.values() for v in parts.values())
+    path, summary = traced
+    assert list(summary.scope_ns) == [m for m in summary.module_ns
+                                      if "jit_stream_call" in m]
+    total = sum(v for paths in summary.scope_ns.values()
+                for v in paths.values())
     assert total == pytest.approx(sum(summary.op_ns.values()), abs=20)
 
 
 def test_scoped_trace_names_each_layer(traced):
-    path, summary, scope_ns, level_ns = traced
-    (parts,) = scope_ns.values()
+    path, summary = traced
+    (paths,) = summary.scope_ns.values()
+    parts = layers.split(paths)
     if path == OLD:
         assert set(parts) == {layers.UNSCOPED}
-        assert not level_ns
+        assert not layers.split(paths, "fabric")
         return
     assert {"chip", "egress", "fabric", "ingress"} <= set(parts)
     assert "plasticity" not in parts and "slots" not in parts
     assert parts.get(layers.UNSCOPED, 0.0) < 0.05 * sum(parts.values())
-    (fabric,) = level_ns.values()
+    fabric = layers.split(paths, "fabric")
     assert set(fabric) == {"leaf", "level0", "level1", "merge"}
     assert sum(fabric.values()) == pytest.approx(parts["fabric"], abs=20)
     # The Pallas pack kernel carries its own name and lies in the merge.
@@ -168,20 +169,23 @@ def test_scoped_trace_names_each_layer(traced):
 
 @pytest.mark.parametrize("tf_op, scope", [
     ("jit(stream_call)/while/body/closed_call/vmap(vmap(jit(searchsorted)))"
-     "/while", (layers.UNSCOPED, None)),
-    ("jit(_step)/slots/dynamic_slice", ("slots", None)),
+     "/while", ()),
+    ("jit(_step)/slots/dynamic_slice", ("slots",)),
     ("jit(f)/while/body/closed_call/vmap(egress)/jit(searchsorted)/gather",
-     ("egress", None)),
+     ("egress",)),
     ("jit(f)/while/body/closed_call/vmap(vmap(fabric))/level1/jit(sort)",
      ("fabric", "level1")),
     ("jit(f)/vmap(fabric)/merge/jit(fused_merge_pack)/spike_router_pack",
      ("fabric", "merge")),
-    ("jit(f)/vmap(fabric)/gather", ("fabric", layers.FABRIC_OTHER)),
-    ("jit(f)/vmap(fabric)/level0/vmap(egress)/x", ("fabric", "level0")),
-    ("", (layers.UNSCOPED, None)),
+    ("jit(f)/vmap(fabric)/gather", ("fabric",)),
+    ("jit(f)/vmap(fabric)/level0/vmap(egress)/x", ("fabric", "level0",
+                                                   "egress")),
+    ("jit(_step)/while/body/closed_call/chip/vmap(br,brn->bn)/dot_general",
+     ("chip",)),
+    ("", ()),
 ])
 def test_scope_of(tf_op, scope):
-    assert layers.scope_of(tf_op) == scope
+    assert layers.scope_path(tf_op) == scope
 
 
 SPANS = [  # (name, start, end, stats): two steps of a host loop, in ns
@@ -247,7 +251,7 @@ def test_span_readers():
         ctx, ("engine.account", "engine.finalize")) == pytest.approx(
             (18 + 6) * 1e-6 / 2)
     layers.note_idle_by_span(ctx)
-    assert ctx["notes"][0].startswith("idle by engine span: host.other")
+    assert ctx["notes"][0].startswith("idle by program span: host.other")
     # A summary without the program's spans or scopes reads nothing.
     bare = {"summary": object(), "program": "jit__step",
             "stats": {"emulated_steps": 16}}
@@ -257,16 +261,14 @@ def test_span_readers():
 
 
 def test_scope_readers_on_the_scoped_trace(traced):
-    path, summary, scope_ns, level_ns = traced
-    summary.scope_ns, summary.level_ns = scope_ns, level_ns
+    path, summary = traced
     summary.program_spans = []
     ctx = {"summary": summary, "program": "jit_stream_call",
            "traffic": {"path": "stream"}, "stats": {"emulated_steps": 6}}
     got = trace_layers.layer_readings(ctx)
     program_ms = summary.modules_matching("jit_stream_call")[0] * 1e-6 / 6
-    scoped = sum(v for k, v in got.items()
-                 if k.endswith("_ms_per_step") and k != "stream."
-                 "device_ms_per_step")
+    outermost = layers.split(layers.program_paths(ctx))
+    scoped = sum(got[f"stream.{k}_ms_per_step"] for k in outermost)
     assert scoped == pytest.approx(program_ms, rel=0.01)
     if path == SCOPED:
         assert got["stream.egress_ms_per_step"] > 0
@@ -305,3 +307,159 @@ def test_engine_spans_of_the_tiny_cell(tmp_path):
     assert layers.readback_mb(ctx) * len(steps) == pytest.approx(
         (len(steps) * raster + finished * row) * 1e-6)
     assert finished == stats["completed"] > 0
+
+
+# -- the open vocabulary -------------------------------------------------------
+
+# Own ns of the ops of ``small_trace_scoped`` by scope and by fabric part,
+# as the reduction read them when it knew the six scopes and the fabric
+# parts by name (one fixed list in ``layers.py``).
+PINNED = {"chip": 7404.921999998391, "egress": 203427.65799997747,
+          "fabric": 264071.80399990827, "ingress": 208375.54600001127,
+          layers.UNSCOPED: 11534.758000098169}
+PINNED_FABRIC = {"leaf": 134469.45799993724, "level0": 59072.58199995011,
+                 "level1": 6140.780000016093, "merge": 64388.98400000483}
+PINNED_UNSCOPED_SHARE = 1.660120057810044
+PINNED_NOTE = ("fabric split, ms per step: leaf 0.0224 (50.9%), level0 "
+               "0.0098 (22.4%), level1 0.0010 (2.3%), merge 0.0107 (24.4%)")
+
+
+@pytest.fixture(scope="module")
+def scoped(tmp_path_factory):
+    path = _unzip(SCOPED, tmp_path_factory.mktemp("scoped"))
+    summary = tracelib.load(path)
+    layers.attach(summary, path)
+    return path, summary
+
+
+def _ctx(summary, path="stream", spans=()):
+    summary.program_spans = list(spans)
+    return {"summary": summary, "program": "jit_stream_call",
+            "traffic": {"path": path}, "stats": {"emulated_steps": 6}}
+
+
+def test_open_reading_keeps_the_numbers_of_the_fixed_list(scoped):
+    _, summary = scoped
+    ctx = _ctx(summary)
+    paths = layers.program_paths(ctx)
+    for scope, ns in PINNED.items():
+        assert layers.time_under(paths, scope) == pytest.approx(ns, abs=1e-3)
+    assert layers.split(paths) == pytest.approx(PINNED, abs=1e-3)
+    assert layers.split(paths, "fabric") == pytest.approx(PINNED_FABRIC,
+                                                          abs=1e-3)
+    for scope in ("plasticity", "slots"):
+        assert layers.scope_ms_per_step(ctx, scope) is None
+    assert layers.unscoped_share(ctx) == pytest.approx(PINNED_UNSCOPED_SHARE,
+                                                       abs=1e-9)
+    assert layers.scope_ms_per_step(ctx, "fabric") == pytest.approx(
+        PINNED["fabric"] * 1e-6 / 6, abs=1e-9)
+    assert ctx["notes"] == [PINNED_NOTE]
+
+
+def test_a_scope_is_read_by_its_path(scoped):
+    """Nested paths and inner scopes are read by name alone; a scope the
+    reduction has never seen is read the same way."""
+    _, summary = scoped
+    ctx = _ctx(summary)
+    paths = layers.program_paths(ctx)
+    assert layers.time_under(paths, "fabric/leaf") == pytest.approx(
+        PINNED_FABRIC["leaf"], abs=1e-3)
+    assert layers.time_under(paths, "level0") == pytest.approx(
+        PINNED_FABRIC["level0"], abs=1e-3)
+    assert layers.time_under(paths, "fabric/merge") == \
+        layers.time_under(paths, "merge")
+    assert layers.time_under(paths, "leaf/fabric") is None
+    assert layers.scope_ms_per_step(ctx, "fabric/leaf") == pytest.approx(
+        PINNED_FABRIC["leaf"] * 1e-6 / 6, abs=1e-9)
+    made_up = {"": 1.0, "round": 2.0, "round/stage": 3.0,
+               "lane/round/stage/x": 4.0}
+    assert layers.time_under(made_up, "round") == 9.0
+    assert layers.time_under(made_up, "round/stage") == 7.0
+    assert layers.time_under(made_up, "stage") == 7.0
+    assert layers.time_under(made_up, layers.UNSCOPED) == 1.0
+    assert layers.split(made_up) == {layers.UNSCOPED: 1.0, "round": 5.0,
+                                     "lane": 4.0}
+    assert layers.split(made_up, "round") == {layers.OTHER: 2.0,
+                                              "stage": 7.0}
+    assert layers.scope_path(
+        "jit(run)/while/body/cond/branch_1_fun/vmap(round)/stage/jit(f)/"
+        "inner/add:") == ("round", "stage")
+
+
+def test_attach_fills_the_summary(scoped):
+    path, summary = scoped
+    fresh = tracelib.load(path)
+    assert fresh.scope_ns is None and fresh.program_spans is None
+    seconds = layers.attach(fresh, path)
+    assert seconds > 0
+    assert fresh.scope_ns == summary.scope_ns
+    assert isinstance(fresh.program_spans, list)
+    # The recorded window holds only the benchmark's own spans.
+    assert fresh.program_spans == []
+
+
+def test_program_spans_are_read_by_name(tmp_path):
+    """Any ``<layer>.<what>`` span of the program is kept with its stats,
+    whatever its layer; the benchmark's own spans and the runtime's events
+    are not.  A counter is read per any span."""
+    import jax
+    import jax.numpy as jnp
+
+    f = jax.jit(lambda x: x * 2 + 1)
+    f(jnp.ones(8)).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench.window"):
+            for k in range(3):
+                with jax.profiler.TraceAnnotation("supervisor.poll",
+                                                  polls=k + 1):
+                    with jax.profiler.TraceAnnotation("supervisor.io_wait"):
+                        f(jnp.ones(8)).block_until_ready()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                     recursive=True)[0]
+    spans = layers.program_spans(xspace.read(path), (0, float("inf")))
+    assert sorted({sp[0] for sp in spans}) == ["supervisor.io_wait",
+                                               "supervisor.poll"]
+    assert [sp[3]["polls"] for sp in spans
+            if sp[0] == "supervisor.poll"] == [1, 2, 3]
+    ctx = {"summary": _Summary(busy=[], spans=spans)}
+    assert layers.stat_per_step(ctx, "supervisor.poll", "polls",
+                                per="supervisor.poll") == 2.0
+    assert layers.span_ms_per_step(ctx, ("supervisor.io_wait",),
+                                   per="supervisor.poll") > 0
+    assert layers.stat_per_step(ctx, "supervisor.poll", "polls") is None
+
+
+LAYER_METRICS = [m["name"] for m in spec.load_benchmark(ROOT)["per_layer"]
+                 if m["source"] in ("program_span", "program_counter")
+                 or (m["name"].endswith("_ms_per_step")
+                     and not m["name"].endswith(".device_ms_per_step"))]
+
+
+@pytest.mark.parametrize("name", LAYER_METRICS)
+def test_metric_file_reads_what_trace_layers_reads(scoped, name):
+    """Each scope, span and counter metric file reads the same number from
+    a summary as ``trace_layers.layer_readings``, or nothing where that
+    reads nothing (the recorded stream program has no ``plasticity`` or
+    ``slots`` scope)."""
+    _, summary = scoped
+    ctx = _ctx(summary, path=name.split(".")[0], spans=SPANS)
+    want = trace_layers.layer_readings(dict(ctx)).get(name)
+    assert spec.reader(name)(ctx) == want
+    assert want is not None or name.endswith(("plasticity_ms_per_step",
+                                              "slots_ms_per_step"))
+
+
+def test_kernel_roofline(scoped):
+    from bench.harness import peaks, readers
+
+    _, summary = scoped
+    ctx = {"summary": summary, "peak": peaks.peaks("TPU v5 lite")}
+    kernel_s = summary.kernels_matching("spike_router_pack") * 1e-9
+    assert kernel_s == pytest.approx(36_118.828e-9, abs=2e-8)
+    share = readers.kernel_roofline(ctx, "spike_router_pack", 8e6, 1e9)
+    assert share == pytest.approx(100 * 8e6 / 819e9 / kernel_s)
+    assert ctx["notes"] == ["spike_router_pack kernel roofline bound by hbm"]
+    assert readers.kernel_roofline(ctx, "spike_router_pack", 0.0,
+                                   1e12) == pytest.approx(
+        100 * 1e12 / 393e12 / kernel_s)
+    assert readers.kernel_roofline(ctx, "no_such_kernel", 8e6, 1e9) is None

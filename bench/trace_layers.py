@@ -1,5 +1,5 @@
 """The per-layer split of one benchmark cell's traced window, read from the
-program's own named scopes and ``engine.*`` spans.
+program's own named scopes and host spans.
 
     python3 bench/trace_layers.py --workload <name> --seed <n> \\
         [--seconds <s>] [--keep <directory>]
@@ -7,14 +7,16 @@ program's own named scopes and ``engine.*`` spans.
 Run on the cell's chips from the root of a checkout.  It sets the cell up as
 ``bench/run.py`` does, drives its traffic under the profiler for the mix's
 ``trace_seconds`` at most, and prints one JSON object as the last line of
-standard output: the device ms per emulated step of each scope
-(``<path>.<scope>_ms_per_step``) with the program's ``unscoped`` share, the
-engine's readback ms and MB, accounting ms and slot occupancy per
-``step()``, the self ms per ``step()`` of every ``engine.*`` span, and,
-for comparison, the benchmark's own per-layer readings of the same trace.
-Notes before it split ``fabric`` by level and the engine's idle time by
-span.  It runs no comparison with the reference.  ``--keep`` keeps the
-profiler's output in the directory given.
+standard output: under ``metrics`` the device ms per emulated step of each
+outermost scope (``<path>.<scope>_ms_per_step``) and of each scope right
+inside one (``<path>.<scope>_<part>_ms_per_step``) with the program's
+``unscoped`` share, the engine's readback ms and MB, accounting ms and slot
+occupancy per ``step()``, and the self ms per ``step()`` of every program
+span; under ``bench`` the cell's own per-layer metrics read from the same
+trace by their files in ``bench/metrics/``.  Notes before it split each
+scope that holds named scopes, and the device's idle time by span.  It
+runs no comparison with the reference.  ``--keep`` keeps the profiler's
+output in the directory given.
 """
 
 import argparse
@@ -29,13 +31,23 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 def layer_readings(ctx: dict) -> dict:
     """The per-layer numbers of a context whose summary carries the scope
-    times and the program's spans."""
+    times and the program's spans: every outermost scope of the program
+    and every scope right inside one, the engine's host readings, and the
+    self time of every program span."""
     from bench.harness import layers, readers
 
-    path = "engine" if ctx["traffic"]["path"] == "engine" else "stream"
-    out = {f"{path}.{scope}_ms_per_step": layers.scope_ms_per_step(ctx,
-                                                                   scope)
-           for scope in (*layers.SCOPES, layers.UNSCOPED)}
+    path = ctx["traffic"]["path"]
+    paths = layers.program_paths(ctx) or {}
+    out = {}
+    for scope in layers.split(paths):
+        out[f"{path}.{scope}_ms_per_step"] = layers.scope_ms_per_step(
+            ctx, scope)
+        if scope == layers.UNSCOPED:
+            continue
+        for part in layers.split(paths, scope):
+            if part != layers.OTHER:
+                out[f"{path}.{scope}_{part}_ms_per_step"] = (
+                    layers.scope_ms_per_step(ctx, f"{scope}/{part}"))
     out[f"{path}.unscoped_share"] = layers.unscoped_share(ctx)
     out[f"{path}.device_ms_per_call"] = readers.program_ms_per_call(ctx)
     out[f"{path}.device_ms_per_step"] = readers.program_ms_per_step(ctx)
@@ -50,11 +62,10 @@ def layer_readings(ctx: dict) -> dict:
         out["engine.slot_occupancy"] = layers.slot_occupancy(ctx)
         out["engine.step_host_ms"] = readers.host_ms_per_span(
             ctx, "bench.engine.step")
-        spans = ctx["summary"].program_spans
-        for name in sorted({sp[0] for sp in spans}):
-            out[f"self_ms_per_step.{name}"] = layers.span_ms_per_step(
-                ctx, (name,))
-        layers.note_idle_by_span(ctx)
+    for name in sorted({sp[0] for sp in ctx["summary"].program_spans or ()}):
+        out[f"self_ms_per_step.{name}"] = layers.span_ms_per_step(
+            ctx, (name,))
+    layers.note_idle_by_span(ctx)
     return {k: v for k, v in out.items() if v is not None}
 
 
@@ -69,12 +80,12 @@ def main(argv=None) -> int:
     import jax
 
     from bench import run as runmod
-    from bench.harness import layers, spec, xspace
+    from bench.harness import layers, peaks, spec
     from bench.harness import trace as tracelib
 
     cell = spec.resolve(spec.load_benchmark(ROOT), args.workload)
     try:
-        runmod.require_chips(cell.chips)
+        devices = runmod.require_chips(cell.chips)
     except runmod.NoChip as e:
         print(f"bench/trace_layers.py: {e}; nothing run", file=sys.stderr)
         return 2
@@ -91,21 +102,21 @@ def main(argv=None) -> int:
             stats = runner.window(seconds)
     xplane = tracelib.find_xplane(logdir)
     summary = tracelib.load(xplane)
-    planes = xspace.read(xplane)
-    summary.scope_ns, summary.level_ns = layers.scope_times(planes,
-                                                            summary.window)
-    summary.program_spans = layers.program_spans(planes, summary.window)
+    reduce_s = layers.attach(summary, xplane)
     ctx = {"cfg": cell.cfg, "traffic": cell.traffic, "stats": stats,
-           "program": runner.program, "summary": summary}
+           "program": runner.program, "summary": summary,
+           "peak": peaks.peaks(devices[0].device_kind),
+           "notes": [f"scope and span reduction: {reduce_s:.3f} s"]}
     result = layer_readings(ctx)
-    for note in stats.get("notes", []) + ctx.get("notes", []):
+    bench = {m["name"]: spec.reader(m["name"])(ctx) for m in cell.per_layer}
+    for note in dict.fromkeys(stats.get("notes", []) + ctx.get("notes", [])):
         print(note, flush=True)
     runner.release()
     print(json.dumps({"workload": args.workload, "seed": args.seed,
                       "window_s": stats["window_s"],
                       "emulated_steps": stats["emulated_steps"],
                       "program_calls": stats["program_calls"],
-                      "metrics": result}), flush=True)
+                      "metrics": result, "bench": bench}), flush=True)
     return 0
 
 
