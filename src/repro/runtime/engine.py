@@ -21,9 +21,10 @@ shared ``FabricPlan``:
   masked (``run_stream(slot_mask=...)``) so they emit no events, cost no
   drop accounting and freeze their plasticity rows;
 * ``collect()`` returns a finished session's spikes plus per-tenant
-  accounting (spike counts, all four drop fields, latency percentiles via
-  the masked per-slot reduction of ``snn.stream.masked_latency_stats``) and
-  frees the slot;
+  accounting (spike counts, all four drop fields, and in a timed engine
+  the latency percentiles, summarised per session on the host by
+  ``snn.stream.masked_latency_stats`` in float64 with no device program)
+  and frees the slot;
 * ``evict()`` checkpoints the tenant's row (ROADMAP: "evict = checkpoint a
   tenant's row") — resubmitting with ``restore_from=`` resumes bit-exactly.
 
@@ -37,17 +38,19 @@ independent batch-1 runs (the engine benchmark's hard parity gate).
 A FIFO request queue with admission-on-free-slot (continuous-batching
 style, after MaxText's prefill/insert/generate engine) sits on top; the CLI
 demo is ``launch/serve_emulation.py`` and the throughput is measured by the
-benchmark cell ``ext4case_96chip.engine_plastic`` (``bench/run.py``).
+benchmark cells ``ext4case_96chip.engine_plastic`` and, timed,
+``backplane_12chip.engine_timed_plastic`` (``bench/run.py``).
 
 The host loop records its work as ``jax.profiler.TraceAnnotation`` spans,
 with counters as their arguments, which a profiler trace records on the
 clock of the device's ops: ``engine.step`` (``live`` slot-steps that advanced a
 session, ``slot_steps``, ``finished``), ``engine.upload`` (``bytes``),
 ``engine.wait``, ``engine.readback`` (``bytes``, ``what``),
-``engine.account``, ``engine.finalize`` (``session``) and ``engine.admit``
-(``session``, ``queued_ms``).  The window program's own work outside
-``run_stream`` runs under the named scope ``slots``.  With no profiler
-running a span costs about a microsecond.
+``engine.account``, ``engine.finalize`` (``session``), ``engine.latency``
+(``samples``: a timed session's delivered events, inside its finalize) and
+``engine.admit`` (``session``, ``queued_ms``).  The window program's own
+work outside ``run_stream`` runs under the named scope ``slots``.  With no
+profiler running a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -451,8 +454,10 @@ class EmulationEngine:
             return None
         samples = (np.concatenate(sess.lat_samples)
                    if sess.lat_samples else np.zeros((0,), np.int32))
-        return stlib.masked_latency_stats(
-            samples, np.ones(samples.shape, bool), strict=False)
+        with jax.profiler.TraceAnnotation("engine.latency",
+                                          samples=samples.size):
+            return stlib.masked_latency_stats(
+                samples, np.ones(samples.shape, bool), strict=False)
 
     def _session_plasticity(self, slot: int):
         if self._plast is None:
@@ -463,7 +468,7 @@ class EmulationEngine:
             row = self._row_plast_like
         else:
             _, row = self._extract_fn(self._state, self._plast,
-                                      jnp.int32(slot))
+                                      np.int32(slot))
         row = self._readback(row, "plasticity")
         return jax.tree.map(lambda a: a[:, 0], row)
 

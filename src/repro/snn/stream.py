@@ -52,6 +52,7 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import fabric as fablib
 from repro.core import latency as latlib
@@ -103,26 +104,33 @@ def masked_latency_stats(latency_ns, latency_valid, *,
     ``count`` key.  Zero delivered events raises under ``strict`` (the
     historical behaviour — an untimed run or a dead stream is a caller
     bug); ``strict=False`` returns NaN-valued stats with ``count == 0``
-    instead, so per-tenant accounting of idle sessions stays total."""
-    lats = jnp.asarray(latency_ns)[jnp.asarray(latency_valid)]
+    instead, so per-tenant accounting of idle sessions stays total.
+
+    Computed on the host in float64 with NumPy (linear-interpolated
+    percentiles at 1, 50 and 99): a sample array has a new length in
+    almost every session, and an eager device reduction would compile
+    again for each.  Device arrays are copied to the host first;
+    ``core.latency.latency_statistics`` is the form for jitted callers."""
+    lats = np.asarray(latency_ns)[np.asarray(latency_valid, bool)]
     count = int(lats.size)
     if count == 0:
         if strict:
             raise ValueError("no delivered events (or run_stream ran "
                              "untimed — pass timed=True)")
         return {**{k: float("nan") for k in _LATENCY_STAT_KEYS}, "count": 0}
-    stats = {k: float(v) for k, v in
-             latlib.latency_statistics(lats.astype(jnp.float32)).items()}
-    stats["count"] = count
-    return stats
+    p01, med, p99 = np.percentile(lats.astype(np.float64), [1.0, 50.0, 99.0])
+    return {"median_ns": float(med), "p01_ns": float(p01),
+            "p99_ns": float(p99), "jitter_ns": float(p99 - p01),
+            "jitter_frac": float((p99 - p01) / med), "count": count}
 
 
 def stream_latency_stats(out: StreamOut, *,
                          strict: bool = True) -> dict[str, float]:
     """Host-side percentile summary of a timed stream's wire latencies.
 
-    Masks the padding slots and reuses ``core.latency.latency_statistics``
-    (median / p01 / p99 / jitter), plus a ``count`` of delivered events.
+    Masks the padding slots and summarises the rest with
+    ``masked_latency_stats`` (median / p01 / p99 / jitter), plus a
+    ``count`` of delivered events.
     Call on concrete (non-traced) outputs.  ``strict=False`` returns
     NaN stats (``count == 0``) instead of raising when nothing was
     delivered — see ``masked_latency_stats``.

@@ -20,6 +20,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core import fabric as fablib
 from repro.core.aggregator import identity_router
 from repro.runtime.engine import EmulationEngine
 from repro.snn import chip as chiplib
@@ -44,32 +45,47 @@ def _stims(cfg, lengths, rate=0.35, seed=7):
             .astype(np.float32) for L in lengths]
 
 
-def _independent_run(cfg, params, stim, *, timed=False, plasticity=None):
+def _backplane_plan(cfg):
+    """A 1-level plan like the backplane's: every chip on one Aggregator,
+    each chip lane admitting 2 events per round, so the uplink drops."""
+    return fablib.compile_fabric(fablib.FabricSpec(
+        levels=(fablib.LevelSpec(fan_in=cfg.n_chips, link_capacity=2),),
+        capacity=cfg.capacity))
+
+
+PLANS = {"star": lambda cfg: None, "one_level_lanes": _backplane_plan}
+
+
+def _independent_run(cfg, params, stim, *, timed=False, plasticity=None,
+                     plan=None):
     drives = jnp.zeros((stim.shape[0], cfg.n_chips, 1, cfg.chip.n_rows))
     drives = drives.at[:, 0, 0].set(jnp.asarray(stim))
     pstate = (netlib.init_slot_plasticity(params, 1)
               if plasticity is not None else None)
     return stlib.run_stream(params, netlib.init_state(cfg, 1), drives, cfg,
                             timed=timed, plasticity=plasticity,
-                            plasticity_state=pstate)
+                            plasticity_state=pstate, fabric=plan)
 
 
-def test_engine_sessions_match_independent_runs():
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_engine_sessions_match_independent_runs(plan):
     """Batched timed+plastic sessions of unequal lengths are bit-exact
     with their independent batch-1 runs — spikes, drops, latency stats
-    and the evolved per-slot weights."""
+    and the evolved per-slot weights — on the default star and on a
+    1-level plan whose chip lanes drop at the uplink."""
     cfg, params = _small_network()
+    plan = PLANS[plan](cfg)
     pcfg = STDPConfig()
     lengths = (10, 7, 4, 12, 9)
     stims = _stims(cfg, lengths)
     eng = EmulationEngine(params, cfg, slots=3, max_steps=max(lengths),
-                          window=4, timed=True, plasticity=pcfg)
+                          window=4, timed=True, plasticity=pcfg, plan=plan)
     sids = [eng.submit(s) for s in stims]
     eng.drain()
-    total_events = 0
+    total_events, uplink = 0, 0
     for sid, stim, L in zip(sids, stims, lengths):
         out = _independent_run(cfg, params, stim, timed=True,
-                               plasticity=pcfg)
+                               plasticity=pcfg, plan=plan)
         r = eng.collect(sid)
         assert r.steps == L
         assert np.array_equal(r.spikes, np.asarray(out.spikes)[:, :, 0])
@@ -87,7 +103,39 @@ def test_engine_sessions_match_independent_runs():
                              jax.tree.leaves(out.plasticity)):
             assert np.array_equal(np.asarray(got), np.asarray(want)[:, 0])
         total_events += ref_lat.size
+        uplink += r.uplink_dropped
     assert total_events > 0, "gate must see real routed traffic"
+    if plan is not None:
+        assert uplink > 0, "the chip lanes must drop at the uplink"
+
+
+def test_timed_engine_sessions_compile_nothing_once_warm():
+    """Once one session has warmed the window program and the row
+    extraction, timed plastic sessions of many distinct lengths (so
+    latency sample arrays of many lengths) finish with no compilation:
+    the per-session latency summary runs on the host."""
+    import pathlib
+    import sys
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.run import CompileCounter
+
+    cfg, params = _small_network()
+    eng = EmulationEngine(params, cfg, slots=3, max_steps=12, window=4,
+                          timed=True, plasticity=STDPConfig())
+    eng.submit(_stims(cfg, (4,), seed=11)[0])
+    eng.drain()
+    lengths = (3, 5, 6, 9, 11, 12, 7)
+    with CompileCounter() as counter:
+        sids = [eng.submit(s)
+                for s in _stims(cfg, lengths, rate=0.9, seed=12)]
+        eng.drain()
+        results = [eng.collect(sid) for sid in sids]
+    assert counter.count == 0
+    assert [r.steps for r in results] == list(lengths)
+    assert len({r.latency["count"] for r in results}) >= 5
 
 
 def test_engine_evict_restore_is_bit_exact(tmp_path):

@@ -314,6 +314,27 @@ def test_latency_stats_non_strict_zero_events_returns_nan_and_count():
     assert loose["median_ns"] == 200.0
 
 
+@pytest.mark.parametrize("n", [1, 2, 5, 101, 4096])
+def test_latency_stats_are_the_float64_percentiles(n):
+    """The summary is NumPy's linear-interpolated percentiles at 1, 50 and
+    99 of the valid samples in float64, exactly, for device and host
+    inputs alike."""
+    rng = np.random.default_rng(n)
+    lats = rng.integers(700, 2400, size=(n, 3), dtype=np.int32)
+    valid = rng.random((n, 3)) < 0.7
+    valid[0, 0] = True
+    x = lats[valid].astype(np.float64)
+    p01, med, p99 = np.percentile(x, [1.0, 50.0, 99.0])
+    want = {"median_ns": med, "p01_ns": p01, "p99_ns": p99,
+            "jitter_ns": p99 - p01, "jitter_frac": (p99 - p01) / med,
+            "count": x.size}
+    assert stlib.masked_latency_stats(lats, valid) == want
+    assert stlib.masked_latency_stats(jnp.asarray(lats),
+                                      jnp.asarray(valid)) == want
+    stats = stlib.masked_latency_stats(lats, valid, strict=False)
+    assert stats == want and isinstance(stats["count"], int)
+
+
 # ---------------------------------------------------------------------------
 # Golden regression fixture (see conftest.py: --regen-golden)
 # ---------------------------------------------------------------------------

@@ -107,7 +107,7 @@ def _trace_engine(tmp_path, **kw):
     stims = _stims(cfg)
     with jax.profiler.trace(str(tmp_path)):
         pending = [[eng.submit(s), s.shape[0]] for s in stims]
-        live, steps, finished = 0, 0, 0
+        live, steps, finished, results = 0, 0, 0, {}
         while eng.active or eng.queued:
             for item in pending[:eng.active]:
                 live += min(WINDOW, item[1])
@@ -115,7 +115,7 @@ def _trace_engine(tmp_path, **kw):
             finished += eng.step()
             steps += 1
             for sid in eng.done:
-                eng.collect(sid)
+                results[sid] = eng.collect(sid)
                 pending[:] = [p for p in pending if p[0] != sid]
     path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
     spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
@@ -124,7 +124,7 @@ def _trace_engine(tmp_path, **kw):
              for line in plane.lines for ev in line.events
              if ev.name.startswith("engine.")]
     return cfg, eng, spans, {"live": live, "steps": steps,
-                             "finished": finished}
+                             "finished": finished, "results": results}
 
 
 def _inside(inner, outer):
@@ -171,6 +171,25 @@ def test_engine_spans_and_counters(tmp_path):
                for sp in reads["raster"])
     assert all(any(_inside(sp, f) for f in by["engine.finalize"])
                for sp in reads["plasticity"])
+
+
+@pytest.mark.parametrize("timed", [True, False])
+def test_latency_span_only_in_a_timed_engine(tmp_path, timed):
+    """A timed engine summarises each finished session's latencies under
+    one ``engine.latency`` span inside its finalize, counting the
+    session's delivered events as ``samples``; an untimed engine records
+    no such span."""
+    _, _, spans, seen = _trace_engine(tmp_path, timed=timed)
+    lat = [sp for sp in spans if sp[0] == "engine.latency"]
+    if not timed:
+        assert not lat
+        return
+    finals = [sp for sp in spans if sp[0] == "engine.finalize"]
+    assert len(lat) == len(LENGTHS)
+    assert all(any(_inside(sp, f) for f in finals) for sp in lat)
+    counts = sorted(r.latency["count"] for r in seen["results"].values())
+    assert sorted(sp[3]["samples"] for sp in lat) == counts
+    assert sum(counts) > 0
 
 
 def test_readback_bytes_match_the_arrays_copied():
